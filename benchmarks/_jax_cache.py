@@ -1,23 +1,29 @@
-"""Shared JAX persistent-compilation-cache setup for the bench drivers."""
+"""The persistent XLA compilation cache of every entry point
+(``chip_smoke.py``, ``benchmarks/simperf.py``, ``benchmarks/run.py``)."""
 
 from __future__ import annotations
 
+import os
 from pathlib import Path
 
+CACHE_DIR = Path(__file__).resolve().parents[1] / "artifacts" / "xla_cache"
 
-def enable_persistent_cache(cache_dir: Path) -> None:
-    """Point the live XLA compile cache at ``cache_dir`` (best-effort:
-    the cache is an optimization, never a requirement)."""
+
+def enable_persistent_cache() -> str:
+    """Turn JAX's persistent compilation cache on and return its
+    directory.  Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX has
+    already taken it as its own setting and nothing else is set; otherwise
+    the cache is the fixed ``artifacts/xla_cache/`` of this checkout.
+    Compile time is set-up time: callers report it, they never turn the
+    cache off to measure it."""
     import jax
-    try:
-        cache_dir.mkdir(parents=True, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", str(cache_dir))
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        # The backend may already be initialized (module-level jnp consts
-        # in repro.core.simlock) — re-point the live cache at the dir.
-        from jax.experimental.compilation_cache import (
-            compilation_cache as cc)
-        cc.reset_cache()
-    except Exception as e:
-        print(f"# persistent compile cache unavailable: {e}")
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    CACHE_DIR.mkdir(parents=True, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
+    # Something may have compiled before this call (module-level jnp
+    # constants in repro.core.simlock): re-point the live cache.
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    cc.reset_cache()
+    return str(CACHE_DIR)

@@ -95,24 +95,45 @@ FIG1_KW = {"tas": dict(w_big=0.15)}
 FIG1_SLO = {"libasl": 1e9, "edf": 100.0}
 
 
+def fig1_cell(name):
+    """fig1's 8-core config for policy ``name`` and the SLO it tracks."""
+    return _cfg(name, 8, **FIG1_KW.get(name, {})), FIG1_SLO.get(name, 1e9)
+
+
 def fig1_policies():
     """The fig1 workload per registered policy — also the acceptance
     benchmark's grid (benchmarks/simperf reuses this, so the perf
     protocol can never drift from the figure it tracks)."""
     from repro.core.policies import REGISTRY
-    return [(name, _cfg(name, 8, **FIG1_KW.get(name, {})),
-             FIG1_SLO.get(name, 1e9)) for name in REGISTRY]
+    return [(name, *fig1_cell(name)) for name in REGISTRY]
+
+
+def fig1_rows(pol, cfg, slo):
+    """One policy's fig1 curve: its eight thread counts in one sweep."""
+    return _sweep_rows(
+        cfg, {"n_cores": list(range(1, 9))},
+        lambda c: f"fig1/{pol}/n{c['n_cores']}",
+        slo_us=slo,
+        extra=lambda c, s: dict(n_threads=int(c["n_cores"])))
 
 
 def fig1_collapse():
     rows = []
     for pol, cfg, slo in fig1_policies():
-        rows += _sweep_rows(
-            cfg, {"n_cores": list(range(1, 9))},
-            lambda c, p=pol: f"fig1/{p}/n{c['n_cores']}",
-            slo_us=slo,
-            extra=lambda c, s: dict(n_threads=int(c["n_cores"])))
+        rows += fig1_rows(pol, cfg, slo)
     return rows
+
+
+def fig1_headline(rows):
+    """The figure's claim: FIFO (MCS) throughput falls from 4 to 8 cores
+    (``mcs_drop`` > 0) and TAS's 8-core CS P99 exceeds FIFO's
+    (``tas_p99_vs_mcs`` > 1)."""
+    def at(policy, n):
+        return next(r for r in rows
+                    if r["policy"] == policy and r["n_threads"] == n)
+    f4, f8, t8 = at("fifo", 4), at("fifo", 8), at("tas", 8)
+    return {"mcs_drop": 1 - f8["tput"] / f4["tput"],
+            "tas_p99_vs_mcs": t8["p99_all"] / f8["p99_all"]}
 
 
 # ---------------------------------------------------------------------------

@@ -19,15 +19,17 @@ Sections:
                    events/s floor on the recorded BENCH_simlock.json
                    + a sharded-vs-unsharded sweep parity
                    probe; nonzero exit on failure.
-                   Opt-in (not part of the default all-sections run): it
-                   virtualizes 8 host devices and pins XLA threading,
-                   which would skew the other sections' baselines
+                   Opt-in (not part of the default all-sections run):
+                   under JAX_PLATFORMS=cpu it virtualizes 8 host devices
+                   and pins XLA threading, which would skew the other
+                   sections' baselines
   paper figures  — discrete-event AMP simulator (benchmarks/paper_figs.py)
   serving/fleet  — engine + dispatch + straggler sims (serving_bench.py);
                    also a CI gate: ASL must hold its TTFT P99 within
                    1.5x its SLO and FIFO must not beat ASL on token
                    throughput — nonzero exit on a break
-  kernels        — per-kernel interpret-mode check vs jnp reference
+  kernels        — per-kernel check vs jnp reference (interpret mode on
+                   the CPU only)
   roofline       — reads artifacts/roofline/*.json (produced by
                    ``python -m benchmarks.roofline``; compile-heavy)
   chaos          — CI gate for the fault-injection layer
@@ -72,14 +74,10 @@ def _run_section(section: str, fns: dict, results: dict):
 def _headline(name, rows) -> str:
     try:
         if name.startswith("fig1"):
-            f4 = next(r for r in rows if r["policy"] == "fifo"
-                      and r["n_threads"] == 4)
-            f8 = next(r for r in rows if r["policy"] == "fifo"
-                      and r["n_threads"] == 8)
-            t8 = next(r for r in rows if r["policy"] == "tas"
-                      and r["n_threads"] == 8)
-            return (f"mcs_drop={1 - f8['tput'] / f4['tput']:.0%};"
-                    f"tas_p99_vs_mcs={t8['p99_all'] / f8['p99_all']:.1f}x")
+            from benchmarks.paper_figs import fig1_headline
+            h = fig1_headline(rows)
+            return (f"mcs_drop={h['mcs_drop']:.0%};"
+                    f"tas_p99_vs_mcs={h['tas_p99_vs_mcs']:.1f}x")
         if name.startswith("fig4"):
             f8 = next(r for r in rows if r["policy"] == "fifo"
                       and r["n_threads"] == 8)
@@ -213,7 +211,8 @@ def _headline(name, rows) -> str:
 
 
 def _kernel_bench(results):
-    """Interpret-mode kernel check + timing vs jnp reference."""
+    """Kernel check + timing vs jnp reference: interpreted on the CPU,
+    compiled by Mosaic anywhere else."""
     import jax
     import jax.numpy as jnp
 
@@ -225,15 +224,17 @@ def _kernel_bench(results):
     q = jax.random.normal(ks[0], (b, h, s, dh), jnp.float32)
     k = jax.random.normal(ks[1], (b, kh, s, dh), jnp.float32)
     v = jax.random.normal(ks[2], (b, kh, s, dh), jnp.float32)
+    interpret = jax.default_backend() == "cpu"
     t0 = time.time()
     out = flash_attention(q, k, v, causal=True, block_q=128, block_k=128,
-                          interpret=True)
+                          interpret=interpret)
     jax.block_until_ready(out)
     dt = (time.time() - t0) * 1e6
     err = float(jnp.max(jnp.abs(
         out - ref.flash_attention_ref(q, k, v, causal=True))))
     results["kernels/flash_attention"] = {"err": err, "us": dt}
-    _emit("kernels/flash_attention_interp", dt, f"max_err={err:.1e}")
+    _emit(f"kernels/flash_attention_{'interp' if interpret else 'mosaic'}",
+          dt, f"max_err={err:.1e}")
 
 
 def _policy_matrix_probe(results) -> bool:
@@ -526,7 +527,7 @@ def _sim_section(results, quick: bool) -> bool:
     results["sim/fig1_sweep"] = rec
     # --quick horizons are compile-dominated, so the wall ratio reads low
     # on a cold compile cache; the full >= 3 acceptance number is owned by
-    # the cache-cold simperf protocol (BENCH_simlock.json).  The smoke
+    # simperf's full-length run (BENCH_simlock.json).  The smoke
     # floor still catches a de-batched engine (48 compiles ~ speedup < 1).
     floor = 1.5 if quick else 3.0
     gate = (rec["speedup_vs_seed_path"] >= floor
@@ -547,8 +548,9 @@ def _sim_section(results, quick: bool) -> bool:
 
     if len(jax.devices()) < 2:
         # The sharded half of the gate cannot run — that is itself a gate
-        # break (jax was imported before our 8-device virtualization, or
-        # the caller pinned a single device): report it, don't skip it.
+        # break (a one-chip host, jax imported before the 8-device CPU
+        # virtualization, or a caller-pinned single device): report it,
+        # don't skip it.
         results["sim/sharded_parity"] = {"devices": 1,
                                          "bit_identical": None}
         _emit("sim/sharded_parity", 0.0,
@@ -728,21 +730,22 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
     sections = set(args.section or DEFAULT_SECTIONS)
 
-    # The sim smoke gate probes the mesh-sharded sweep path: virtualize 8
-    # host devices, and pin XLA's intra-op threading exactly as
-    # benchmarks/simperf.py does (the three policy sweeps compile
-    # concurrently; unpinned they thrash the container's 2 cores and the
-    # speedup gate reads low).  Only effective before the first jax
-    # import, so a caller-provided XLA_FLAGS wins.
+    # The sim smoke gate probes the mesh-sharded sweep path.  Under
+    # JAX_PLATFORMS=cpu it runs on 8 virtual host devices, with XLA's CPU
+    # intra-op threading pinned exactly as benchmarks/simperf.py does (the
+    # policy sweeps compile concurrently; unpinned they thrash the host's
+    # cores and the speedup gate reads low).  On a TPU host the mesh is
+    # the chips.  Only effective before the first jax import, so a
+    # caller-provided XLA_FLAGS wins.
     if "sim" in sections:
-        from repro.launch.xla_flags import ensure_host_devices, prepend
-        prepend("--xla_cpu_multi_thread_eigen=false",
-                "intra_op_parallelism_threads=1")
-        ensure_host_devices(8)
+        from repro.launch.xla_flags import (cpu_platform,
+                                            ensure_host_devices, prepend)
+        if cpu_platform():
+            prepend("--xla_cpu_multi_thread_eigen=false",
+                    "intra_op_parallelism_threads=1")
+            ensure_host_devices(8)
 
-    # Repeated bench invocations (and CI re-runs on an unchanged image)
-    # skip every XLA compile.
-    enable_persistent_cache(ART.parent / "xla_cache")
+    enable_persistent_cache()
     ART.mkdir(parents=True, exist_ok=True)
     results = {}
     from benchmarks import paper_figs

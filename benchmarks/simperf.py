@@ -7,6 +7,11 @@ executables the figure compiled.  For fig1 it additionally times the
 one-event-per-iteration loop (``chunk=1``) — against the batched sweep
 (one executable per policy, all thread counts as an active-core mask).
 
+Compiles go through the persistent compilation cache
+(``benchmarks/_jax_cache.py``), so the cold-minus-hot compile estimate is
+the set-up a user with that cache pays.  Every record names the device
+it ran on.
+
 Writes ``BENCH_simlock.json`` at the repo root so the perf trajectory is
 tracked from PR to PR (protocol in docs/simulator.md).
 
@@ -18,25 +23,26 @@ from __future__ import annotations
 import sys
 
 # Both must precede the first jax import (hence PYTHONPATH=src in every
-# invocation): per-op shapes in the simulator are tiny (N<=8 cores), so
-# XLA's intra-op threading buys nothing and only thrashes — pinning it
-# lets the concurrently-dispatched policy sweeps (and their compiles)
-# overlap cleanly on the container's cores.  --devices N virtualizes N
-# host-platform devices so the sweeps can shard their cell dimension
-# over a data mesh.
-from repro.launch.xla_flags import (argv_device_count, ensure_host_devices,
-                                    prepend)
+# invocation), and both apply only where JAX is pinned to the CPU: per-op
+# shapes in the simulator are tiny (N<=8 cores), so XLA's CPU intra-op
+# threading buys nothing and only thrashes — pinning it lets the
+# concurrently-dispatched policy sweeps (and their compiles) overlap
+# cleanly on the host's cores.  --devices N virtualizes N host-platform
+# devices there so the sweeps can shard their cell dimension over a data
+# mesh; on a TPU host the mesh is made of the chips.
+from repro.launch.xla_flags import (argv_device_count, cpu_platform,
+                                    ensure_host_devices, prepend)
 
-prepend("--xla_cpu_multi_thread_eigen=false",
-        "intra_op_parallelism_threads=1")
-_n = int(argv_device_count(sys.argv, 1))
-if _n > 1:
-    ensure_host_devices(_n)
+if cpu_platform():
+    prepend("--xla_cpu_multi_thread_eigen=false",
+            "intra_op_parallelism_threads=1")
+    _n = int(argv_device_count(sys.argv, 1))
+    if _n > 1:
+        ensure_host_devices(_n)
 
 import argparse
 import dataclasses
 import json
-import platform
 import time
 from pathlib import Path
 
@@ -198,26 +204,24 @@ def main():
                     help="comma-separated figure subset")
     ap.add_argument("--skip-figures", action="store_true",
                     help="only the fig1 batched-vs-seed acceptance bench")
-    ap.add_argument("--cache", action="store_true",
-                    help="enable the persistent XLA compile cache (OFF by "
-                         "default here: compile-cost measurements must be "
-                         "cache-cold to stay comparable across runs)")
     ap.add_argument("--devices", type=int, default=1,
-                    help="virtualize N host devices and shard every sweep's "
-                         "cell dimension over a 1-D data mesh (multi-device "
-                         "path; collective accounting goes nonzero)")
+                    help="shard every sweep's cell dimension over a 1-D "
+                         "data mesh of N devices: the chips of a TPU host, "
+                         "or N virtual host devices under JAX_PLATFORMS=cpu "
+                         "(collective accounting goes nonzero)")
     args = ap.parse_args()
-    if args.cache:
-        enable_persistent_cache(ROOT / "artifacts" / "xla_cache")
+    enable_persistent_cache()
     if args.devices > 1:
         from benchmarks import paper_figs
         from repro.launch.mesh import make_sweep_mesh
         paper_figs.MESH = make_sweep_mesh(args.devices)
 
     figs = set(args.figs.split(",")) if args.figs else None
+    dev = jax.devices()[0]
     rec = {
         "bench": "simlock",
-        "host": platform.machine(),
+        "device": {"platform": dev.platform, "kind": dev.device_kind,
+                   "count": len(jax.devices())},
         "jax": jax.__version__,
         "quick": bool(args.quick),
         "chunk": sl.SimConfig().chunk,

@@ -226,7 +226,8 @@ class SimConfig:
     # Bit-identical results either way (the kernel runs the same traced
     # step); _canon keeps the bit in the jit key (different lowering ->
     # different executable) but nothing else about sweep semantics
-    # changes.  CPU builds run the kernel in interpret mode.
+    # changes.  The kernel is interpreted only where it is lowered for the
+    # CPU; on TPU, Mosaic refuses the step today and the compile raises.
     use_pallas: bool = False
     n_cores: int = 8
     big: tuple = (1, 1, 1, 1, 0, 0, 0, 0)          # 4 big + 4 little (M1)
@@ -1195,7 +1196,8 @@ def _simulate(cfg: SimConfig, tb: SimTables, pm: SimParams,
     if cfg.use_pallas:
         # Fused path (repro.kernels.simstep): the whole chunk retires
         # inside one Pallas kernel with the packed state VMEM-resident.
-        # Same _step closure -> bit-identical to the jnp body below.
+        # Same _step closure -> bit-identical to the jnp body below
+        # wherever it compiles (interpret mode on the CPU only).
         from repro.kernels import simstep
 
         def body(s):
@@ -1236,6 +1238,14 @@ def _leaf_sig(x):
     return (tuple(x.shape), jnp.dtype(x.dtype).name, sh)
 
 
+def _batched(ccfg: SimConfig):
+    """The sweep program: ``(tb, pm, windows0) -> state``, every leaf with
+    a leading sweep-cell axis.  The masked (branchless) step keeps the
+    vmap scatter-shaped — a vmapped ``lax.switch`` would select over every
+    branch's full state."""
+    return jax.vmap(lambda a, b, c: _simulate(ccfg, a, b, c, masked=True))
+
+
 def _batch_executable(ccfg: SimConfig, tb: SimTables, pm: SimParams,
                       windows0):
     key = (ccfg, tuple(_leaf_sig(x)
@@ -1243,20 +1253,13 @@ def _batch_executable(ccfg: SimConfig, tb: SimTables, pm: SimParams,
     with _BATCH_LOCK:
         hit = _BATCH_EXECS.get(key)
     if hit is None:
-        def run(t, p, w):
-            """All leaves carry a leading sweep-cell axis.  The masked
-            (branchless) step keeps the vmap scatter-shaped — a vmapped
-            ``lax.switch`` would select over every branch's full state."""
-            return jax.vmap(
-                lambda a, b, c: _simulate(ccfg, a, b, c, masked=True))(
-                    t, p, w)
         # NO donation here (unlike _run_single, where bench2's window
         # carry makes it worth it): the windows0 buffer is tiny, and
         # donating it lets the output `window` leaf alias an input whose
         # host memory XLA CPU occasionally reuses while a *different*
         # executable (e.g. a mesh-sharded sweep) runs concurrently —
         # observed as flaky single-leaf corruption of async results.
-        compiled = jax.jit(run).lower(tb, pm, windows0).compile()
+        compiled = jax.jit(_batched(ccfg)).lower(tb, pm, windows0).compile()
         rec = executable_stats(compiled)
         rec["n_cells"] = int(np.shape(pm.slo)[0])
         rec["devices"] = max((x.sharding.num_devices
@@ -1514,10 +1517,7 @@ def _sweep_resumable(ccfg: SimConfig, tb: SimTables, pm: SimParams, w0,
         pm_k = jax.tree.map(lambda x: x[lo:hi], pm)
         w_k = w0[lo:hi]
         if done is not None and k <= done:
-            target = jax.eval_shape(
-                lambda a, b, c: jax.vmap(
-                    lambda x, y, z: _simulate(ccfg, x, y, z, masked=True)
-                )(a, b, c), tb_k, pm_k, w_k)
+            target = jax.eval_shape(_batched(ccfg), tb_k, pm_k, w_k)
             parts.append(ckpt.restore(d, k, target))
             continue
         compiled, rec = _batch_executable(ccfg, tb_k, pm_k, w_k)
@@ -1528,43 +1528,17 @@ def _sweep_resumable(ccfg: SimConfig, tb: SimTables, pm: SimParams, w0,
     return jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0), *parts)
 
 
-def sweep(cfg: SimConfig, axes: dict, *, slo_us=1e9, seed=0,
-          windows0=None, product: bool = True,
-          mesh=None, data_axis="data",
-          resume_dir=None, resume_chunk: int = 8):
-    """Run a whole parameter sweep as ONE vmapped, compiled call.
+def _sweep_inputs(cfg: SimConfig, axes: dict, *, slo_us=1e9, seed=0,
+                  windows0=None, product: bool = True, mesh=None,
+                  data_axis="data"):
+    """Validate a :func:`sweep` grid and build its traced inputs.
 
-    ``axes`` maps axis names (see ``SWEEPABLE``) to value lists.  With
-    ``product=True`` (default) the grid is the cross-product in the dict's
-    key order; with ``product=False`` all lists must have equal length and
-    are zipped (pre-flattened grids, e.g. paired slo/window cells).
-
-    ``n_cores`` cells run padded to ``cfg.n_cores`` with an active-core
-    mask — identical results to an unpadded run, one executable for all.
-
-    ``mesh`` (a ``jax.sharding.Mesh``) shards the cell dimension over the
-    mesh's ``data_axis`` (``repro.dist.sharding.build_sweep_rules``); cells
-    are padded to the next multiple of the shard count (duplicates of the
-    last cell, trimmed from the result), so every device carries an equal
-    contiguous row split and results stay bit-identical to the unsharded
-    run (docs/simulator.md §Sharded sweeps).
-
-    ``resume_dir`` makes a long sweep resumable: cells run in
-    ``resume_chunk``-sized slices, each checkpointed atomically on
-    completion (``repro.ckpt.checkpointer``); re-running the same sweep
-    with the same directory restores completed chunks and continues,
-    bit-identical to an uninterrupted run.  Not composable with
-    ``mesh``.
-
-    Returns ``(state, grid)``: ``state`` leaves have a leading cell axis;
-    ``grid`` maps axis name -> np.ndarray of per-cell values.  Non-swept
-    values come from ``cfg`` / ``slo_us`` / ``seed`` / ``windows0``.
-    """
+    Returns ``(cfg, cells, tb, pm, w0)``: the template with every gate the
+    axes need switched on (its ``_canon`` is the executable's key), one
+    dict per cell, and the stacked per-cell tables, params and initial
+    windows — padded and placed on ``mesh`` when one is given."""
     if not axes:
         raise ValueError("empty sweep: pass at least one axis")
-    if resume_dir is not None and mesh is not None:
-        raise ValueError("resume_dir does not compose with mesh-sharded "
-                         "sweeps; run chunked-resumable sweeps unsharded")
     # A "policy" axis merges its values into ONE multi-policy
     # executable: the template grows a ``policy_set`` (jit-static — it
     # fixes the handler union compiled into the HLO) while each cell's
@@ -1669,7 +1643,7 @@ def sweep(cfg: SimConfig, axes: dict, *, slo_us=1e9, seed=0,
         rules = build_sweep_rules(mesh, data_axis=data_axis)
         n_shards = rules.num_shards("cells")
         pad = (-n_cells) % n_shards
-        if pad:  # equal row splits: duplicate the last cell, trim below
+        if pad:  # equal row splits: duplicate the last cell (sweep trims)
             rep = partial(jnp.repeat, repeats=pad, axis=0)
             tb = jax.tree.map(lambda x: jnp.concatenate([x, rep(x[-1:])]),
                               tb)
@@ -1680,6 +1654,47 @@ def sweep(cfg: SimConfig, axes: dict, *, slo_us=1e9, seed=0,
         tb, pm = jax.device_put((tb, pm), ns)
         w0 = jax.device_put(w0, ns)
 
+    return cfg, cells, tb, pm, w0
+
+
+def sweep(cfg: SimConfig, axes: dict, *, slo_us=1e9, seed=0,
+          windows0=None, product: bool = True,
+          mesh=None, data_axis="data",
+          resume_dir=None, resume_chunk: int = 8):
+    """Run a whole parameter sweep as ONE vmapped, compiled call.
+
+    ``axes`` maps axis names (see ``SWEEPABLE``) to value lists.  With
+    ``product=True`` (default) the grid is the cross-product in the dict's
+    key order; with ``product=False`` all lists must have equal length and
+    are zipped (pre-flattened grids, e.g. paired slo/window cells).
+
+    ``n_cores`` cells run padded to ``cfg.n_cores`` with an active-core
+    mask — identical results to an unpadded run, one executable for all.
+
+    ``mesh`` (a ``jax.sharding.Mesh``) shards the cell dimension over the
+    mesh's ``data_axis`` (``repro.dist.sharding.build_sweep_rules``); cells
+    are padded to the next multiple of the shard count (duplicates of the
+    last cell, trimmed from the result), so every device carries an equal
+    contiguous row split and results stay bit-identical to the unsharded
+    run (docs/simulator.md §Sharded sweeps).
+
+    ``resume_dir`` makes a long sweep resumable: cells run in
+    ``resume_chunk``-sized slices, each checkpointed atomically on
+    completion (``repro.ckpt.checkpointer``); re-running the same sweep
+    with the same directory restores completed chunks and continues,
+    bit-identical to an uninterrupted run.  Not composable with
+    ``mesh``.
+
+    Returns ``(state, grid)``: ``state`` leaves have a leading cell axis;
+    ``grid`` maps axis name -> np.ndarray of per-cell values.  Non-swept
+    values come from ``cfg`` / ``slo_us`` / ``seed`` / ``windows0``.
+    """
+    if resume_dir is not None and mesh is not None:
+        raise ValueError("resume_dir does not compose with mesh-sharded "
+                         "sweeps; run chunked-resumable sweeps unsharded")
+    cfg, cells, tb, pm, w0 = _sweep_inputs(
+        cfg, axes, slo_us=slo_us, seed=seed, windows0=windows0,
+        product=product, mesh=mesh, data_axis=data_axis)
     if resume_dir is not None:
         st = _sweep_resumable(_canon(cfg), tb, pm, w0, resume_dir,
                               resume_chunk)
@@ -1687,11 +1702,13 @@ def sweep(cfg: SimConfig, axes: dict, *, slo_us=1e9, seed=0,
         compiled, rec = _batch_executable(_canon(cfg), tb, pm, w0)
         _log_sweep(rec)
         st = compiled(tb, pm, w0)
-    if pad:
+    n_cells = len(cells)
+    if np.shape(pm.slo)[0] > n_cells:   # mesh padding: trim the pad lanes
         st = jax.tree.map(lambda x: x[:n_cells], st)
+    tbl_axes = table_axes()
     grid = {k: np.asarray([cell[k] for cell in cells], dtype=object)
             if k in tbl_axes else np.asarray([cell[k] for cell in cells])
-            for k in names}
+            for k in axes}
     return st, grid
 
 

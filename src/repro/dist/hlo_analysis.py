@@ -124,12 +124,8 @@ def analytic_hbm_bytes(cfg, shape, rules=None) -> float:
 
 
 def xla_cost(compiled) -> dict:
-    """XLA ``cost_analysis`` as one flat dict, across jax versions (older
-    releases return the dict directly, newer ones a one-element list)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost or {})
+    """XLA ``cost_analysis`` of a compiled executable as a plain dict."""
+    return dict(compiled.cost_analysis() or {})
 
 
 def executable_stats(compiled) -> dict:

@@ -1,23 +1,3 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
+# OPTIONAL layer. Add <name>.py (or .cu) + ref.py ONLY
 # for compute hot-spots the paper itself optimizes with a custom
 # kernel. Leave this package empty if the paper has none.
-
-def _missing_compiler_params(*_a, **_k):  # pragma: no cover
-    raise ImportError(
-        "jax.experimental.pallas.tpu (or its CompilerParams /"
-        " TPUCompilerParams) is unavailable in this jax build; the pure-"
-        "NumPy reference path (repro.kernels.ref) still works — update "
-        "repro/kernels/__init__.py for the new Pallas API to use the "
-        "TPU kernels")
-
-
-# jax >= 0.5 renamed TPUCompilerParams -> CompilerParams; support both,
-# and degrade to a deferred error (not an import-time crash) so the
-# reference implementations stay importable on pallas-less jax builds.
-try:  # pragma: no cover - exercised only on minimal jax wheels
-    from jax.experimental.pallas import tpu as _pltpu
-    CompilerParams = getattr(
-        _pltpu, "CompilerParams",
-        getattr(_pltpu, "TPUCompilerParams", _missing_compiler_params))
-except ImportError:
-    CompilerParams = _missing_compiler_params

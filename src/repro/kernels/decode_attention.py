@@ -22,8 +22,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams as _CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -109,7 +107,7 @@ def _call_with_prefetch(kernel, qg, k_cache, v_cache, lengths, b, kh, g, dh,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kh, g, dh), qg.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lengths, qg, k_cache, v_cache)

@@ -27,8 +27,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams as _CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -124,7 +122,7 @@ def flash_attention(q, k, v, *, causal=True, window=0, block_q=128,
             pltpu.VMEM((block_q,), jnp.float32),      # running denom
             pltpu.VMEM((block_q, dh), jnp.float32),   # output accumulator
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -28,8 +28,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams as _CompilerParams
-
 from repro.kernels.flash_attention import NEG_INF, flash_attention
 
 
@@ -185,7 +183,7 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal=True, window=0,
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, dh), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -212,7 +210,7 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal=True, window=0,
                    jax.ShapeDtypeStruct((b, h, t, dh), v.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, dh), jnp.float32),
                         pltpu.VMEM((block_k, dh), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams as _CompilerParams
-
 
 def _kernel(q_ref, k_ref, v_ref, ig_ref, fg_ref, c0_ref, n0_ref, m0_ref,
             o_ref, cT_ref, nT_ref, mT_ref, C_ref, n_ref, m_ref, *, block_s,
@@ -111,7 +109,7 @@ def mlstm_scan(q, k, v, i_gate, f_gate, carry=None, *, block_s=128,
             pltpu.VMEM((dh,), jnp.float32),
             pltpu.VMEM((1,), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf, igf, fgf, c0, n0, m0)

@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams as _CompilerParams
-
 
 def _kernel(a_ref, x_ref, h0_ref, o_ref, h_ref, *, block_s):
     si = pl.program_id(1)
@@ -64,7 +62,7 @@ def rglru_scan(a, x, h0=None, *, block_s=128, block_c=128, interpret=False):
                                lambda bc, si, nc=nc: (bc // nc, si, bc % nc)),
         out_shape=jax.ShapeDtypeStruct((b, s, r), a.dtype),
         scratch_shapes=[pltpu.VMEM((block_c,), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(a, x, h0)

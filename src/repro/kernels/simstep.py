@@ -3,31 +3,34 @@
 One :func:`fused_chunk` call retires a whole ``cfg.chunk`` of events
 inside a single ``pl.pallas_call``: the traced pytrees (``SimTables``,
 ``SimParams``, ``SimState``) are *packed* — leaves grouped by
-(dtype, shape) and stacked into a few i32/f32/u32 vectors — handed to
+(dtype, shape) and stacked into a few i32/f32/u32 arrays — handed to
 the kernel as whole-array VMEM refs, unpacked back into pytrees inside
 the kernel, and the per-event step (argmin over the event clock +
 masked scatter/gather handler updates) runs as an in-kernel
-``lax.scan``.  On a TPU the whole hot state is then VMEM-resident for
-the duration of the chunk instead of bouncing per-op through HBM.
+``lax.fori_loop``.  On a TPU the whole hot state would then stay
+VMEM-resident for the duration of the chunk.
 
 The step callable itself is the engine's ``simlock._step`` closure —
 the kernel adds no semantics of its own, so results are bit-identical
 to the plain jnp lowering (``tests/test_fused.py`` asserts exact
-equality across every registered policy).  On this CPU container the
-kernel executes in ``interpret=True`` mode (the body runs as traced
-XLA ops — correctness only); set env ``REPRO_PALLAS_COMPILE=1`` on a
-real TPU to compile it to Mosaic, exactly like ``repro.kernels.ops``.
+equality across every registered policy).
+
+Interpret mode follows the platform the call is lowered for: the CPU
+lowering interprets the kernel (correctness only), every other
+platform compiles it with Mosaic and raises whatever Mosaic refuses.
+On TPU v5e, Mosaic refuses the engine's step today: ``argmin`` over the
+i32 event clock ("Only float32 is supported") and, past that, the
+per-core ``dynamic_slice`` gathers (docs/simulator.md §Fused step
+kernel).
 """
 
 from __future__ import annotations
 
-import os
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-INTERPRET = os.environ.get("REPRO_PALLAS_COMPILE", "0") != "1"
 
 
 def _group(leaves) -> dict:
@@ -40,38 +43,36 @@ def _group(leaves) -> dict:
     return groups
 
 
+def _stack(leaves, idx):
+    """One packed array: the group's leaves stacked on a leading axis,
+    kept at least 2-D so a vmapped call's squeezed cell axis never lands
+    in the last two block dimensions (Mosaic's (8, 128) tiling rule
+    holds for a block dim equal to the array dim, not for a squeezed 1)."""
+    x = jnp.stack([leaves[i] for i in idx])
+    return x.reshape(len(idx), 1) if x.ndim == 1 else x
+
+
 def _pack(leaves, groups):
-    return [jnp.stack([leaves[i] for i in idx]) for idx in groups.values()]
+    return [_stack(leaves, idx) for idx in groups.values()]
 
 
-def _unpack_refs(refs, groups, n_leaves):
-    """Read each packed ref back into per-leaf arrays (ref[j] is a
-    load, so after this the kernel computes on values, not refs)."""
+def _unpack(packed, groups, n_leaves):
+    """Split packed refs or arrays back into per-leaf values (``ref[j]``
+    is a load, so inside the kernel this yields values, not refs)."""
     out = [None] * n_leaves
-    for r, idx in zip(refs, groups.values()):
+    for r, ((_, shape), idx) in zip(packed, groups.items()):
         for j, i in enumerate(idx):
-            out[i] = r[j]
+            out[i] = r[j].reshape(shape)
     return out
 
 
-def _unpack_arrays(arrs, groups, n_leaves):
-    out = [None] * n_leaves
-    for a, idx in zip(arrs, groups.values()):
-        for j, i in enumerate(idx):
-            out[i] = a[j]
-    return out
-
-
-def fused_chunk(step, tb, pm, st, chunk: int, *, interpret=None):
+def fused_chunk(step, tb, pm, st, chunk: int):
     """Advance ``st`` by ``chunk`` events of ``step`` in one kernel.
 
     ``step(tb, pm, st) -> st`` must be shape-preserving and already
     horizon-guarded (the engine's live-guard retires past-horizon
     steps as no-ops, which is what makes a fixed-size chunk safe).
-    ``interpret=None`` follows the module :data:`INTERPRET` switch.
     """
-    if interpret is None:
-        interpret = INTERPRET
     # Pallas kernels may not close over constant arrays (e.g. the
     # engine's horizon scalar — jax.closure_convert would leave such
     # integer consts baked in): trace the step to a jaxpr and hoist
@@ -99,23 +100,28 @@ def fused_chunk(step, tb, pm, st, chunk: int, *, interpret=None):
         st_refs = refs[n_ro:n_ro + n_st]
         out_refs = refs[n_ro + n_st:]
         tb_k, pm_k, consts_k = jax.tree_util.tree_unflatten(
-            ro_def, _unpack_refs(ro_refs, ro_groups, len(ro_leaves)))
+            ro_def, _unpack(ro_refs, ro_groups, len(ro_leaves)))
         st_k = jax.tree_util.tree_unflatten(
-            st_def, _unpack_refs(st_refs, st_groups, len(st_leaves)))
+            st_def, _unpack(st_refs, st_groups, len(st_leaves)))
 
-        def body(s, _):
-            return step_c(tb_k, pm_k, s, consts_k), None
-
-        st_out = jax.lax.scan(body, st_k, None, length=max(chunk, 1))[0]
+        st_out = jax.lax.fori_loop(
+            0, max(chunk, 1),
+            lambda _, s: step_c(tb_k, pm_k, s, consts_k), st_k)
         out_leaves = jax.tree_util.tree_leaves(st_out)
         for r, idx in zip(out_refs, st_groups.values()):
-            r[...] = jnp.stack([out_leaves[i] for i in idx])
+            r[...] = _stack(out_leaves, idx)
 
-    outs = pl.pallas_call(
-        kernel,
-        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
-                   for x in st_packed],
-        interpret=interpret,
-    )(*ro_packed, *st_packed)
+    def call(*packed, interpret):
+        return pl.pallas_call(
+            kernel,
+            out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
+                       for x in st_packed],
+            interpret=interpret,
+        )(*packed)
+
+    # Interpret only where the call is lowered for the CPU.
+    outs = jax.lax.platform_dependent(
+        *ro_packed, *st_packed, cpu=partial(call, interpret=True),
+        default=partial(call, interpret=False))
     return jax.tree_util.tree_unflatten(
-        st_def, _unpack_arrays(outs, st_groups, len(st_leaves)))
+        st_def, _unpack(outs, st_groups, len(st_leaves)))

@@ -15,6 +15,15 @@ import os
 HOST_DEVICE_FLAG = "xla_force_host_platform_device_count"
 
 
+def cpu_platform() -> bool:
+    """Whether JAX is pinned to the CPU (``JAX_PLATFORMS=cpu``) — the
+    only case in which the CPU threading flags and virtual host devices
+    apply.  Read from the environment because it must be known before
+    jax is imported; on a TPU host the chips are the devices."""
+    first = os.environ.get("JAX_PLATFORMS", "").split(",")[0]
+    return first.strip().lower() == "cpu"
+
+
 def prepend(*flags: str) -> None:
     """Add ``flags`` to XLA_FLAGS, skipping any whose name (the part
     before ``=``) the caller already set — the environment wins.  The
