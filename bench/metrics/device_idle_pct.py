@@ -1,0 +1,10 @@
+"""device_idle_pct: the share of the traced window in which no operation
+ran on the device, 1 - (union of device-op intervals) / window, averaged
+over the chips the cell uses."""
+
+
+def read(ctx):
+    t = ctx["trace"]
+    if not t["window_s"] or not t["busy_s"]:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
