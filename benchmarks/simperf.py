@@ -64,15 +64,20 @@ def _events(st) -> int:
     return int(np.sum(np.asarray(st.events)))
 
 
-def _hlo_accounting(log_start: int) -> dict:
-    """Aggregate the analytic HLO accounting of every sweep executable run
-    since ``log_start`` (repro.dist.hlo_analysis via simlock's AOT compile
-    records; cache hits included, single-run ``sl.run`` cells excluded)."""
-    recs = sl.sweep_log()[log_start:]
+def _log_mark() -> int:
+    """The ``seq`` the simulator's next logged call will carry."""
+    log = sl.sweep_log()
+    return log[-1]["seq"] + 1 if log else 0
+
+
+def _hlo_accounting(mark: int) -> dict:
+    """Aggregate the collective schedule of every sweep executable run
+    since ``mark`` (a :func:`_log_mark`; simlock's call log, cache hits
+    included, single-run ``sl.run`` cells excluded)."""
+    recs = [r for r in sl.sweep_log()
+            if r["seq"] >= mark and r["kind"] == "sweep"]
     return {
         "sweep_calls": len(recs),
-        "flops": sum(r["flops"] for r in recs),
-        "bytes_accessed": sum(r["bytes_accessed"] for r in recs),
         "collective_count": sum(r["collectives"]["total_count"]
                                 for r in recs),
         "collective_bytes": sum(r["collectives"]["total_bytes"]
@@ -119,7 +124,7 @@ def bench_fig1_batched_vs_seed(quick: bool) -> dict:
         min(len(cfgs), (os.cpu_count() or 2) + 1)
     with ThreadPoolExecutor(n_workers) as pool:
         c0 = _compiles()
-        h0 = len(sl.sweep_log())
+        h0 = _log_mark()
         t0 = time.time()
         events = sum(pool.map(one_policy, cfgs))
         batched_cold = time.time() - t0
@@ -168,7 +173,7 @@ def bench_figures(quick: bool, figs=None) -> dict:
         if figs and name not in figs:
             continue
         c0 = _compiles()
-        h0 = len(sl.sweep_log())
+        h0 = _log_mark()
         t0 = time.time()
         rows = fn()
         wall = time.time() - t0

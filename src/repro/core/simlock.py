@@ -52,9 +52,9 @@ table models multi-class tenants side by side.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import itertools
-import threading
 from functools import partial
 from typing import NamedTuple
 
@@ -75,9 +75,9 @@ from repro.core.policies.base import ticks as _ticks
 from repro.core.policies.base import weighted_pick as _weighted_pick
 from repro.core import columns as colreg
 from repro.core import energy as _energy  # registers the DVFS/power columns
-from repro.dist.hlo_analysis import executable_stats
+from repro.dist.hlo_analysis import collective_stats
 from repro.core.policies.base import lock_of as _lock_of
-from repro.core import stats
+from repro.core import calllog, stats
 from repro.faults import model as flt
 from repro.workloads import generators as wlg
 from repro.workloads import keys as wlk
@@ -1131,6 +1131,11 @@ def _dispatch_table(cfg: SimConfig):
     return table
 
 
+# Phase id -> the name scope its handler's device ops carry.
+_HANDLER_SCOPES = {NONCRIT: "simlock/acquire", HOLDER: "simlock/release",
+                   STANDBY: "simlock/standby", ARRIVAL: "simlock/arrival"}
+
+
 def _step(cfg: SimConfig, tb: SimTables, pm: SimParams, horizon,
           st: SimState, masked: bool) -> SimState:
     """One event — or nothing, when the run is already past its horizon
@@ -1157,28 +1162,38 @@ def _step(cfg: SimConfig, tb: SimTables, pm: SimParams, horizon,
                      events=st.events + jnp.where(live, 1, 0))
     table = _dispatch_table(cfg)
 
+    # Each handler's device ops carry its name scope (``simlock/acquire``
+    # ...) in both lowerings: a profiler trace then says which handler
+    # the loop's time went to.  Metadata only.
     if masked:
         ph = st.phase[c]
         for phase, fn in table:
-            st = fn(st, cfg, tb, pm, c, t,
-                    jnp.logical_and(live, ph == phase))
+            with jax.named_scope(_HANDLER_SCOPES[phase]):
+                st = fn(st, cfg, tb, pm, c, t,
+                        jnp.logical_and(live, ph == phase))
         # QUEUED/SPIN at the head of the clock: defensive re-park.
-        park = jnp.logical_and(live, jnp.logical_or(ph == QUEUED, ph == SPIN))
-        return st._replace(t_ready=st.t_ready.at[c].set(
-            jnp.where(park, INF, st.t_ready[c])))
+        with jax.named_scope("simlock/park"):
+            park = jnp.logical_and(live,
+                                   jnp.logical_or(ph == QUEUED, ph == SPIN))
+            return st._replace(t_ready=st.t_ready.at[c].set(
+                jnp.where(park, INF, st.t_ready[c])))
 
     def noop(s):
-        return s._replace(t_ready=s.t_ready.at[c].set(INF))
+        with jax.named_scope("simlock/park"):
+            return s._replace(t_ready=s.t_ready.at[c].set(INF))
 
     def dead(s):
         return s
 
-    def bind(fn):
-        return lambda s: fn(s, cfg, tb, pm, c, t, True)
+    def bind(phase, fn):
+        def branch(s):
+            with jax.named_scope(_HANDLER_SCOPES[phase]):
+                return fn(s, cfg, tb, pm, c, t, True)
+        return branch
 
     by_phase = dict(table)
     n_phases = ARRIVAL + 1
-    branches = [bind(by_phase[p]) if p in by_phase else noop
+    branches = [bind(p, by_phase[p]) if p in by_phase else noop
                 for p in range(n_phases)] + [dead]
     branch = jnp.where(live, st.phase[c], n_phases)
     return jax.lax.switch(branch, branches, st)
@@ -1222,15 +1237,16 @@ def _run_single(ccfg: SimConfig, tb: SimTables, pm: SimParams, windows0):
 
 # --------------------------------------------------------------------------
 # Batched executables: AOT-compiled (lower -> compile -> call) instead of a
-# plain jit so every executable's accounting — XLA FLOPs/bytes and the
-# collective schedule of mesh-sharded sweeps — is captured at compile time
-# (benchmarks/simperf.py records it next to wall-clock per figure).
+# plain jit so every executable's collective schedule (nonzero only for
+# mesh-sharded sweeps) is read off the compiled HLO at compile time.
 # Cache key = (canon cfg, arg shapes/dtypes/shardings): the same one-
 # executable-per-(policy, program) discipline as the jit it replaces.
 # --------------------------------------------------------------------------
 
 _BATCH_EXECS: dict = {}          # key -> (compiled, record)
-_BATCH_LOCK = threading.Lock()   # dict access only; compiles overlap
+_BATCH_LOCK = calllog.LOCK       # dict and call-log access; compiles overlap
+_EXE_ORDER = itertools.count()   # compile order of the simulator's programs
+_RUN_EXES: dict = {}             # canon cfg -> its _run_single's place
 
 
 def _leaf_sig(x):
@@ -1248,11 +1264,16 @@ def _batched(ccfg: SimConfig):
 
 def _batch_executable(ccfg: SimConfig, tb: SimTables, pm: SimParams,
                       windows0):
+    """``(compiled, record, hit)`` for these inputs; a miss compiles inside
+    a ``compile`` span.  The record is the executable's: ``exe`` (its place
+    in compile order), ``collectives``, ``n_cells`` and ``devices``."""
     key = (ccfg, tuple(_leaf_sig(x)
                        for x in jax.tree.leaves((tb, pm, windows0))))
     with _BATCH_LOCK:
-        hit = _BATCH_EXECS.get(key)
-    if hit is None:
+        found = _BATCH_EXECS.get(key)
+    if found is not None:
+        return found + (True,)
+    with calllog.span("compile"):
         # NO donation here (unlike _run_single, where bench2's window
         # carry makes it worth it): the windows0 buffer is tiny, and
         # donating it lets the output `window` leaf alias an input whose
@@ -1260,14 +1281,26 @@ def _batch_executable(ccfg: SimConfig, tb: SimTables, pm: SimParams,
         # executable (e.g. a mesh-sharded sweep) runs concurrently —
         # observed as flaky single-leaf corruption of async results.
         compiled = jax.jit(_batched(ccfg)).lower(tb, pm, windows0).compile()
-        rec = executable_stats(compiled)
-        rec["n_cells"] = int(np.shape(pm.slo)[0])
-        rec["devices"] = max((x.sharding.num_devices
-                              for x in jax.tree.leaves((tb, pm, windows0))
-                              if isinstance(x, jax.Array)), default=1)
+        rec = {"collectives": collective_stats(compiled.as_text()),
+               "n_cells": int(np.shape(pm.slo)[0]),
+               "devices": max((x.sharding.num_devices
+                               for x in jax.tree.leaves((tb, pm, windows0))
+                               if isinstance(x, jax.Array)), default=1)}
         with _BATCH_LOCK:
-            hit = _BATCH_EXECS.setdefault(key, (compiled, rec))
-    return hit
+            if key not in _BATCH_EXECS:
+                rec["exe"] = next(_EXE_ORDER)
+                _BATCH_EXECS[key] = (compiled, rec)
+            return _BATCH_EXECS[key] + (False,)
+
+
+def _call_batch(ccfg: SimConfig, tb: SimTables, pm: SimParams, windows0):
+    """Run the batched executable inside a ``dispatch`` span and note it
+    in the open call's record."""
+    with calllog.span("dispatch"):
+        compiled, erec, hit = _batch_executable(ccfg, tb, pm, windows0)
+        st = compiled(tb, pm, windows0)
+    calllog.current().update(erec, hit=hit)
+    return st
 
 
 def n_batch_executables() -> int:
@@ -1277,43 +1310,53 @@ def n_batch_executables() -> int:
 
 
 def executable_records() -> list:
-    """Per-executable accounting records in compile order: XLA flops /
-    bytes_accessed, the collective schedule (nonzero only for mesh-sharded
-    sweeps), cell count and device count."""
+    """Per-executable records in compile order: the place in compile
+    order, the collective schedule (nonzero only for mesh-sharded sweeps),
+    cell count and device count."""
     with _BATCH_LOCK:
         return [rec for _, rec in _BATCH_EXECS.values()]
 
 
-_SWEEP_LOG: list = []
-MAX_SWEEP_LOG = 4096
-
-
-def _log_sweep(rec: dict) -> None:
-    with _BATCH_LOCK:
-        _SWEEP_LOG.append(rec)
-        if len(_SWEEP_LOG) > MAX_SWEEP_LOG:  # bound long-lived processes
-            del _SWEEP_LOG[:-MAX_SWEEP_LOG]
+MAX_SWEEP_LOG = calllog.MAX_RECORDS
 
 
 def sweep_log() -> list:
-    """One record per :func:`sweep` call (cache hits included) — lets the
-    bench attribute executable accounting to the figure that ran it.
-    Holds the most recent ``MAX_SWEEP_LOG`` calls; slice-by-snapshot-index
-    consumers (benchmarks/simperf.py) are stable as long as fewer than
-    that many sweeps happen between snapshot and read."""
-    with _BATCH_LOCK:
-        return list(_SWEEP_LOG)
+    """The call log, oldest first: one record per :func:`sweep` call (per
+    computed slice on the resumable path), :func:`run`,
+    :func:`sweep_summaries` and :func:`summarize` of device state, with
+    the seconds of each phase, compile seconds and input arrays
+    (:mod:`repro.core.calllog`; docs/simulator.md §Observing a run).  A
+    sweep's record also holds its executable's ``devices``, ``n_cells`` and
+    ``collectives``.  Holds the most recent ``MAX_SWEEP_LOG`` records; each
+    has its ``seq``, the call's place in the process."""
+    return calllog.records()
+
+
+def _n_leaves(*trees) -> int:
+    return len(jax.tree.leaves(trees))
 
 
 def run(cfg: SimConfig, slo_us, seed=0, windows0=None) -> SimState:
     """Run one simulation; slo_us/seed may be traced scalars.
     ``windows0`` carries AIMD state across phases (Bench-2) and is DONATED —
     pass a fresh array (reuse the returned ``state.window`` instead)."""
-    tb = build_tables(cfg)
-    pm = build_params(cfg, slo_us, seed)
-    w0 = _default_windows(cfg) if windows0 is None else \
-        jnp.asarray(windows0, jnp.float32)
-    return _run_single(_canon(cfg), tb, pm, w0)
+    with calllog.call("run") as rec:
+        with calllog.span("build"):
+            tb = build_tables(cfg)
+            pm = build_params(cfg, slo_us, seed)
+            w0 = _default_windows(cfg) if windows0 is None else \
+                jnp.asarray(windows0, jnp.float32)
+            ccfg = _canon(cfg)
+        # The first call of a program compiles inside its dispatch.
+        with calllog.span("dispatch"):
+            n0 = _run_single._cache_size()
+            st = _run_single(ccfg, tb, pm, w0)
+            hit = _run_single._cache_size() == n0
+        if not hit:
+            _RUN_EXES[ccfg] = next(_EXE_ORDER)
+        rec.update(exe=_RUN_EXES.get(ccfg), hit=hit,
+                   arrays=_n_leaves(tb, pm) + 1)
+    return st
 
 
 # --------------------------------------------------------------------------
@@ -1466,14 +1509,18 @@ def _cell_params(cfg: SimConfig, cell: dict, slo_us, seed) -> SimParams:
 
 
 def _sweep_resumable(ccfg: SimConfig, tb: SimTables, pm: SimParams, w0,
-                     resume_dir, chunk: int) -> SimState:
+                     resume_dir, chunk: int, calls) -> SimState:
     """Run the batched sweep in ``chunk``-cell slices, checkpointing
     each completed slice atomically (repro.ckpt.checkpointer) so an
     interrupted long sweep resumes from the last completed chunk
     instead of recomputing from cell 0.  Per-cell results are
     bit-identical to the one-shot path: vmap lanes are independent (the
     live-guard no-ops finished lanes), so slicing the cell axis cannot
-    perturb any cell's trajectory."""
+    perturb any cell's trajectory.
+
+    Every computed slice logs a call record of its own: ``calls`` (an
+    ``ExitStack``) holds the sweep's open record, which the first computed
+    slice completes; later slices open theirs."""
     import json
     from pathlib import Path
 
@@ -1513,16 +1560,21 @@ def _sweep_resumable(ccfg: SimConfig, tb: SimTables, pm: SimParams, w0,
     done = ckpt.latest_step(d)          # chunks 0..done are on disk
     parts = []
     for k, (lo, hi) in enumerate(bounds):
-        tb_k = jax.tree.map(lambda x: x[lo:hi], tb)
-        pm_k = jax.tree.map(lambda x: x[lo:hi], pm)
-        w_k = w0[lo:hi]
         if done is not None and k <= done:
-            target = jax.eval_shape(_batched(ccfg), tb_k, pm_k, w_k)
+            tb_k = jax.tree.map(lambda x: x[lo:hi], tb)
+            pm_k = jax.tree.map(lambda x: x[lo:hi], pm)
+            target = jax.eval_shape(_batched(ccfg), tb_k, pm_k, w0[lo:hi])
             parts.append(ckpt.restore(d, k, target))
             continue
-        compiled, rec = _batch_executable(ccfg, tb_k, pm_k, w_k)
-        _log_sweep(rec)
-        st_k = compiled(tb_k, pm_k, w_k)
+        rec = calllog.current() or calls.enter_context(calllog.call("sweep"))
+        with calllog.span("build"):
+            tb_k = jax.tree.map(lambda x: x[lo:hi], tb)
+            pm_k = jax.tree.map(lambda x: x[lo:hi], pm)
+            w_k = w0[lo:hi]
+        rec["lanes"] = hi - lo
+        rec["arrays"] += _n_leaves(tb_k, pm_k) + 1
+        st_k = _call_batch(ccfg, tb_k, pm_k, w_k)
+        calls.close()                   # the slice's record joins the log
         ckpt.save(d, k, st_k)
         parts.append(st_k)
     return jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0), *parts)
@@ -1657,6 +1709,21 @@ def _sweep_inputs(cfg: SimConfig, axes: dict, *, slo_us=1e9, seed=0,
     return cfg, cells, tb, pm, w0
 
 
+def _n_input_arrays(axes: dict, n_cells: int, tb: SimTables,
+                    pm: SimParams, mesh) -> int:
+    """Device arrays a sweep's input build makes, counted from one cell's
+    leaves: every cell's params (and tables, when a table axis is swept;
+    else one set), the stacked leaves, the windows, and on a mesh the
+    placed (and padded) copies."""
+    n_tb, n_pm = _n_leaves(tb), _n_leaves(pm)
+    tables = n_cells if any(k in table_axes() for k in axes) else 1
+    n = n_cells * n_pm + tables * n_tb + n_pm + n_tb + 1
+    if mesh is not None:
+        padded = np.shape(pm.slo)[0] > n_cells
+        n += (1 + padded) * (n_tb + n_pm + 1)
+    return n
+
+
 def sweep(cfg: SimConfig, axes: dict, *, slo_us=1e9, seed=0,
           windows0=None, product: bool = True,
           mesh=None, data_axis="data",
@@ -1692,19 +1759,23 @@ def sweep(cfg: SimConfig, axes: dict, *, slo_us=1e9, seed=0,
     if resume_dir is not None and mesh is not None:
         raise ValueError("resume_dir does not compose with mesh-sharded "
                          "sweeps; run chunked-resumable sweeps unsharded")
-    cfg, cells, tb, pm, w0 = _sweep_inputs(
-        cfg, axes, slo_us=slo_us, seed=seed, windows0=windows0,
-        product=product, mesh=mesh, data_axis=data_axis)
-    if resume_dir is not None:
-        st = _sweep_resumable(_canon(cfg), tb, pm, w0, resume_dir,
-                              resume_chunk)
-    else:
-        compiled, rec = _batch_executable(_canon(cfg), tb, pm, w0)
-        _log_sweep(rec)
-        st = compiled(tb, pm, w0)
-    n_cells = len(cells)
-    if np.shape(pm.slo)[0] > n_cells:   # mesh padding: trim the pad lanes
-        st = jax.tree.map(lambda x: x[:n_cells], st)
+    with contextlib.ExitStack() as calls:
+        rec = calls.enter_context(calllog.call("sweep"))
+        with calllog.span("build"):
+            cfg, cells, tb, pm, w0 = _sweep_inputs(
+                cfg, axes, slo_us=slo_us, seed=seed, windows0=windows0,
+                product=product, mesh=mesh, data_axis=data_axis)
+        n_cells = len(cells)
+        rec["lanes"] = n_cells
+        rec["arrays"] = _n_input_arrays(axes, n_cells, tb, pm, mesh)
+        if resume_dir is not None:
+            st = _sweep_resumable(_canon(cfg), tb, pm, w0, resume_dir,
+                                  resume_chunk, calls)
+        else:
+            st = _call_batch(_canon(cfg), tb, pm, w0)
+            if np.shape(pm.slo)[0] > n_cells:   # mesh padding: trim
+                with calllog.span("dispatch"):
+                    st = jax.tree.map(lambda x: x[:n_cells], st)
     tbl_axes = table_axes()
     grid = {k: np.asarray([cell[k] for cell in cells], dtype=object)
             if k in tbl_axes else np.asarray([cell[k] for cell in cells])
@@ -1721,22 +1792,47 @@ def sweep_slo(cfg: SimConfig, slo_us_values, seed=0) -> SimState:
 
 def sweep_summaries(cfg: SimConfig, st: SimState, grid: dict,
                     warmup: int = 32, slo_us=None) -> list:
-    """Host-side per-cell summaries of a sweep result (one np transfer).
-    ``slo_us`` (or a swept ``slo_us`` axis) adds the goodput metrics —
-    see :func:`summarize`."""
-    st_np = jax.tree.map(np.asarray, st)
-    n_cells = len(next(iter(grid.values()))) if grid else \
-        st_np.events.shape[0]
-    out = []
-    for i in range(n_cells):
-        cell_st = jax.tree.map(lambda x: x[i], st_np)
-        n_act = int(grid["n_cores"][i]) if "n_cores" in grid else None
-        cell_slo = float(grid["slo_us"][i]) if "slo_us" in grid else slo_us
-        s = summarize(cfg, cell_st, warmup, n_active=n_act,
-                      slo_us=cell_slo)
-        s.update({k: grid[k][i] for k in grid})
-        out.append(s)
+    """Host-side per-cell summaries of a sweep result (one transfer of the
+    leaves the summaries read).  ``slo_us`` (or a swept ``slo_us`` axis)
+    adds the goodput metrics — see :func:`summarize`."""
+    with calllog.call("sweep_summaries") as rec:
+        host = _to_host(cfg, st)
+        with calllog.span("reduce"):
+            n_cells = len(next(iter(grid.values()))) if grid else \
+                host.events.shape[0]
+            rec["lanes"] = n_cells
+            names = _summary_leaves(cfg)
+            out = []
+            for i in range(n_cells):
+                cell_st = host._replace(
+                    **{k: getattr(host, k)[i] for k in names})
+                n_act = int(grid["n_cores"][i]) if "n_cores" in grid \
+                    else None
+                cell_slo = float(grid["slo_us"][i]) if "slo_us" in grid \
+                    else slo_us
+                s = summarize(cfg, cell_st, warmup, n_active=n_act,
+                              slo_us=cell_slo)
+                s.update({k: grid[k][i] for k in grid})
+                out.append(s)
     return out
+
+
+def _summary_leaves(cfg: SimConfig) -> tuple:
+    """The state leaves :func:`summarize` reads."""
+    names = ("t", "events", "ep_lat", "ep_cnt", "cs_lat", "cs_cnt",
+             "window", "energy")
+    return names + ("ep_hist", "cs_hist") if cfg.hist else names
+
+
+def _to_host(cfg: SimConfig, st: SimState) -> SimState:
+    """``st`` with the leaves the summaries read on the host: the wait for
+    the device loop and the copy, each in its span of the open call."""
+    names = _summary_leaves(cfg)
+    with calllog.span("wait"):
+        jax.block_until_ready([getattr(st, k) for k in names])
+    with calllog.span("transfer"):
+        got = jax.device_get([getattr(st, k) for k in names])
+    return st._replace(**dict(zip(names, got)))
 
 
 # --------------------------------------------------------------------------
@@ -1833,7 +1929,20 @@ def summarize(cfg: SimConfig, st: SimState, warmup: int = 32,
     ``n_active`` slices per-core outputs for padded sweep cells.
     ``slo_us`` adds goodput: the fraction of sampled epochs within the
     per-core SLO (``slo_us * slo_scale[c]``) and the epochs/s that
-    fraction represents — the chaos figures' useful-work metric."""
+    fraction represents — the chaos figures' useful-work metric.
+    Given device state it logs a call record (:func:`sweep_log`)."""
+    if any(isinstance(getattr(st, k), jax.Array)
+           for k in _summary_leaves(cfg)):
+        with calllog.call("summarize"):
+            st = _to_host(cfg, st)
+            with calllog.span("reduce"):
+                return _summary(cfg, st, warmup, n_active, slo_us)
+    return _summary(cfg, st, warmup, n_active, slo_us)
+
+
+def _summary(cfg: SimConfig, st: SimState, warmup: int, n_active,
+             slo_us) -> dict:
+    """:func:`summarize` of a state whose read leaves are on the host."""
     n = cfg.n_cores if n_active is None else int(n_active)
     big = np.asarray(cfg.big[:n], bool)
     ep_lat = np.asarray(st.ep_lat)[:n]

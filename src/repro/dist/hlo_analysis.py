@@ -128,17 +128,6 @@ def xla_cost(compiled) -> dict:
     return dict(compiled.cost_analysis() or {})
 
 
-def executable_stats(compiled) -> dict:
-    """Per-executable accounting: XLA FLOPs/bytes plus the collective
-    schedule parsed from the compiled HLO (the dry-run/bench record)."""
-    cost = xla_cost(compiled)
-    return {
-        "flops": float(cost.get("flops", 0.0)),
-        "bytes_accessed": float(cost.get("bytes accessed", 0.0)),
-        "collectives": collective_stats(compiled.as_text()),
-    }
-
-
 def collective_stats(hlo_text: str, n_devices: int | None = None) -> dict:
     """Parse the compiled HLO: per-collective op counts and result bytes.
 

@@ -257,15 +257,13 @@ def test_sharded_executable_records_collectives():
     mesh = _mesh()
     cfg = sl.SimConfig(policy="tas", sim_time_us=2_000.0)
     axes = {"w_big": [0.5, 1.0, 2.0, 4.0] * 2}
-    n0 = len(sl.sweep_log())
     sl.sweep(cfg, axes)
     sl.sweep(cfg, axes, mesh=mesh)
-    unsharded, sharded = sl.sweep_log()[n0:]
+    unsharded, sharded = sl.sweep_log()[-2:]
     assert unsharded["devices"] == 1
     assert unsharded["collectives"]["total_count"] == 0
     assert sharded["devices"] == len(jax.devices())
     assert sharded["collectives"]["total_count"] > 0
-    assert sharded["flops"] >= 0.0
 
 
 def test_sweep_rules_degrade_without_data_axis():
