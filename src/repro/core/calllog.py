@@ -4,7 +4,7 @@
 slice on the resumable path), ``run``, ``sweep_summaries`` and
 ``summarize`` of device state, and times its phases as spans:
 
-* ``build``: the host input build and its host-to-device arrays;
+* ``build``: the host input build and its one placement on the device;
 * ``compile``: a batched executable's lower, compile (or persistent-cache
   load) and accounting, on a cache miss;
 * ``dispatch``: the executable's lookup and asynchronous call;
@@ -69,7 +69,7 @@ def call(kind: str, **fields):
     (a call that raises leaves none).  Yields the record, a fresh dict:
     ``kind``, ``lanes``, ``exe`` (the executable's index in compile
     order), ``hit`` (no compile was needed), ``phases`` (seconds per
-    span), ``compile_s``, ``arrays`` (device arrays the input build made),
+    span), ``compile_s``, ``arrays`` (leaves the input build placed),
     then ``seq`` (the call's place in the process) once logged."""
     rec = {"kind": kind, "lanes": 1, "exe": None, "hit": None,
            "phases": dict.fromkeys(PHASES[kind], 0.0), "compile_s": 0.0,

@@ -241,8 +241,9 @@ class LockPolicy:
 
     # -- state-slot declaration -------------------------------------------
     def init_params(self, cfg) -> dict:
-        """Policy-owned traced knobs -> ``SimParams.pol`` (read
-        ``policy_opts(cfg)`` for defaults; called with the REAL cfg)."""
+        """Policy-owned traced knobs -> ``SimParams.pol``, as numpy
+        scalars (the engine places them; read ``policy_opts(cfg)`` for
+        defaults; called with the REAL cfg)."""
         return {}
 
     def init_state(self, cfg, tb, pm) -> dict:

@@ -31,6 +31,7 @@ through — a slow waiter is deferred at most ``race_bound`` grants.
 from __future__ import annotations
 
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.columns import ColumnSpec, register_column
 # Guarantees the ``dvfs`` column this policy reads is registered even
@@ -58,7 +59,7 @@ class DvfsRacePolicy(LockPolicy):
     sweep_axes = {"race_bound": "race_bound"}
 
     def init_params(self, cfg):
-        return {"race_bound": jnp.int32(
+        return {"race_bound": np.int32(
             policy_opts(cfg).get("race_bound", DEFAULT_BOUND))}
 
     def init_state(self, cfg, tb, pm):

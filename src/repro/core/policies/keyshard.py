@@ -35,6 +35,7 @@ unchanged.
 from __future__ import annotations
 
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.policies import register
 from repro.core.policies.base import (INF, LockPolicy, grant, policy_opts,
@@ -91,7 +92,7 @@ class KsErewPolicy(LockPolicy):
     host_dispatch = "key-erew"
 
     def init_params(self, cfg):
-        return {"erew_bound": jnp.int32(
+        return {"erew_bound": np.int32(
             policy_opts(cfg).get("erew_bound", DEFAULT_BOUND))}
 
     def init_state(self, cfg, tb, pm):
@@ -120,10 +121,10 @@ class KsCrewPolicy(LockPolicy):
 
     def init_params(self, cfg):
         kw = policy_opts(cfg)
-        return {"crew_wfrac": jnp.float32(kw.get("crew_wfrac",
-                                                 DEFAULT_WFRAC)),
-                "crew_bound": jnp.int32(kw.get("crew_bound",
-                                               DEFAULT_BOUND))}
+        return {"crew_wfrac": np.float32(kw.get("crew_wfrac",
+                                                DEFAULT_WFRAC)),
+                "crew_bound": np.int32(kw.get("crew_bound",
+                                              DEFAULT_BOUND))}
 
     def init_state(self, cfg, tb, pm):
         return {"crew_ctr": jnp.zeros(cfg.n_locks, jnp.int32)}
@@ -162,7 +163,7 @@ class KsJbsqPolicy(LockPolicy):
     host_dispatch = "key-jbsq"
 
     def init_params(self, cfg):
-        return {"jbsq_k": jnp.int32(
+        return {"jbsq_k": np.int32(
             policy_opts(cfg).get("jbsq_k", DEFAULT_BOUND))}
 
     def init_state(self, cfg, tb, pm):

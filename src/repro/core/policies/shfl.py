@@ -17,6 +17,7 @@ the same scan restricted to big waiters.
 from __future__ import annotations
 
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.policies import register
 from repro.core.policies.base import (INF, LockPolicy, grant, policy_opts,
@@ -34,7 +35,7 @@ class ShflPolicy(LockPolicy):
     sweep_axes = {"shfl_bound": "shfl_bound"}
 
     def init_params(self, cfg):
-        return {"shfl_bound": jnp.int32(
+        return {"shfl_bound": np.int32(
             policy_opts(cfg).get("shfl_bound", DEFAULT_BOUND))}
 
     def init_state(self, cfg, tb, pm):

@@ -610,7 +610,8 @@ def _and_gate(cond, gate):
 
 
 def build_tables(cfg: SimConfig) -> SimTables:
-    """Precompute the per-(core, segment) duration tables once per run.
+    """Precompute the per-(core, segment) duration tables once per run,
+    as host arrays.
 
     Every registered :class:`~repro.core.columns.ColumnSpec` is
     materialized into ``SimTables.col`` — encoded, then padded with its
@@ -625,9 +626,9 @@ def build_tables(cfg: SimConfig) -> SimTables:
     n = cfg.n_cores
     s = len(cfg.seg_cs_us)
     f = colreg.COLUMNS["dvfs"].host_values(cfg, n)
-    col = {spec.name: jnp.asarray(
+    col = {spec.name: np.asarray(
         spec.host_values(cfg, n),
-        jnp.int32 if spec.dtype == "i32" else jnp.float32)
+        np.int32 if spec.dtype == "i32" else np.float32)
         for spec in colreg.COLUMNS.values()}
     # Streaming-histogram edge parameterization, precomputed host-side
     # in TICKS (the unit latency samples are recorded in).  Always
@@ -635,19 +636,19 @@ def build_tables(cfg: SimConfig) -> SimTables:
     h_log2_lo, h_inv_log2g = stats.layout(
         cfg.hist_lo_us * US, cfg.hist_hi_us * US, max(cfg.hist_buckets, 4))
     return SimTables(
-        big=jnp.asarray(cfg.big[:n], jnp.int32),
-        cs_dur=jnp.asarray(
+        big=np.asarray(cfg.big[:n], np.int32),
+        cs_dur=np.asarray(
             [[_ticks(cfg.seg_cs_us[j] * cfg.speed_cs[c] / f[c])
-              for j in range(s)] for c in range(n)], jnp.int32),
-        nc_dur=jnp.asarray(
+              for j in range(s)] for c in range(n)], np.int32),
+        nc_dur=np.asarray(
             [[_ticks(cfg.seg_noncrit_us[j] * cfg.speed_nc[c] / f[c])
-              for j in range(s)] for c in range(n)], jnp.int32),
-        inter=jnp.asarray(
+              for j in range(s)] for c in range(n)], np.int32),
+        inter=np.asarray(
             [_ticks(cfg.inter_epoch_us * cfg.speed_nc[c]) for c in range(n)],
-            jnp.int32),
-        seg_lock=jnp.asarray(cfg.seg_lock, jnp.int32),
-        hist_log2_lo=jnp.float32(h_log2_lo),
-        hist_inv_log2g=jnp.float32(h_inv_log2g),
+            np.int32),
+        seg_lock=np.asarray(cfg.seg_lock, np.int32),
+        hist_log2_lo=np.float32(h_log2_lo),
+        hist_inv_log2g=np.float32(h_inv_log2g),
         col=col)
 
 
@@ -678,7 +679,8 @@ def with_columns(cfg: SimConfig, **cols) -> SimConfig:
 
 
 def build_params(cfg: SimConfig, slo_us, seed=0, n_active=None) -> SimParams:
-    """SimParams from config defaults (each field is a sweep axis)."""
+    """SimParams from config defaults (each field is a sweep axis), as
+    host scalars; a traced ``slo_us`` or ``seed`` stays traced."""
     pol_params = _active_policy(cfg).init_params(cfg)
     # Every policy_kw key must land in a traced pol slot — a typo'd knob
     # silently running with its default would be the one misconfiguration
@@ -688,49 +690,49 @@ def build_params(cfg: SimConfig, slo_us, seed=0, n_active=None) -> SimParams:
         raise ValueError(
             f"unknown policy_kw {sorted(unknown)} for policy "
             f"{cfg.policy!r}; known knobs: {sorted(pol_params)}")
-    slo = (slo_us * US).astype(jnp.float32) if hasattr(slo_us, "astype") \
-        else jnp.float32(_ticks(slo_us))
+    slo = (slo_us * US).astype(np.float32) if hasattr(slo_us, "astype") \
+        else np.float32(_ticks(slo_us))
     ks_theta, ks_zeta, ks_eta, ks_alpha = wlk.zipf_consts(
         max(cfg.n_keys, 1), cfg.zipf_theta)
     return SimParams(
         slo=slo,
-        pol_id=jnp.int32(POLICIES[cfg.policy]),
-        w_big=jnp.float32(cfg.w_big),
-        prop_n=jnp.int32(cfg.prop_n),
-        n_active=jnp.int32(cfg.n_cores if n_active is None else n_active),
-        seed=jnp.int32(seed) if not hasattr(seed, "dtype")
-        else seed.astype(jnp.int32),
-        horizon=jnp.int32(_ticks(cfg.sim_time_us)),
-        long_prob=jnp.float32(cfg.long_epoch_prob),
-        long_scale=jnp.float32(cfg.long_epoch_scale),
-        wakeup=jnp.int32(_ticks(cfg.wakeup_us)),
-        unit0=jnp.float32(aimd.unit_for(_ticks(cfg.default_window_us),
-                                        cfg.pct)),
-        wl_process=jnp.int32(wlg.ARRIVALS[cfg.wl_process]),
-        wl_service=jnp.int32(wlg.SERVICES[cfg.wl_service]),
-        wl_rate=jnp.float32(cfg.wl_rate),
-        wl_cv=jnp.float32(cfg.wl_cv),
-        wl_mix=jnp.float32(cfg.wl_mix),
-        wl_mix_scale=jnp.float32(cfg.wl_mix_scale),
-        wl_burst=jnp.float32(cfg.wl_burst),
-        wl_burst_len=jnp.float32(cfg.wl_burst_len),
-        wl_amp=jnp.float32(cfg.wl_amp),
-        wl_period=jnp.float32(_ticks(
+        pol_id=np.int32(POLICIES[cfg.policy]),
+        w_big=np.float32(cfg.w_big),
+        prop_n=np.int32(cfg.prop_n),
+        n_active=np.int32(cfg.n_cores if n_active is None else n_active),
+        seed=np.int32(seed) if not hasattr(seed, "dtype")
+        else seed.astype(np.int32),
+        horizon=np.int32(_ticks(cfg.sim_time_us)),
+        long_prob=np.float32(cfg.long_epoch_prob),
+        long_scale=np.float32(cfg.long_epoch_scale),
+        wakeup=np.int32(_ticks(cfg.wakeup_us)),
+        unit0=np.float32(aimd.unit_for(_ticks(cfg.default_window_us),
+                                       cfg.pct)),
+        wl_process=np.int32(wlg.ARRIVALS[cfg.wl_process]),
+        wl_service=np.int32(wlg.SERVICES[cfg.wl_service]),
+        wl_rate=np.float32(cfg.wl_rate),
+        wl_cv=np.float32(cfg.wl_cv),
+        wl_mix=np.float32(cfg.wl_mix),
+        wl_mix_scale=np.float32(cfg.wl_mix_scale),
+        wl_burst=np.float32(cfg.wl_burst),
+        wl_burst_len=np.float32(cfg.wl_burst_len),
+        wl_amp=np.float32(cfg.wl_amp),
+        wl_period=np.float32(_ticks(
             cfg.wl_period_us if cfg.wl_period_us > 0.0
             else cfg.sim_time_us)),
-        preempt_rate=jnp.float32(cfg.preempt_rate),
-        preempt_scale=jnp.float32(_ticks(cfg.preempt_scale_us)),
-        churn_rate=jnp.float32(cfg.churn_rate),
-        churn_period=jnp.int32(max(_ticks(cfg.churn_period_us), 1)),
-        straggle_rate=jnp.float32(cfg.straggle_rate),
-        straggle_scale=jnp.float32(cfg.straggle_scale),
-        ks_keys=jnp.int32(cfg.n_keys),
-        ks_theta=jnp.float32(ks_theta),
-        ks_zeta=jnp.float32(ks_zeta),
-        ks_eta=jnp.float32(ks_eta),
-        ks_alpha=jnp.float32(ks_alpha),
-        ks_locks=jnp.int32(cfg.n_locks),
-        hist_warmup=jnp.int32(cfg.hist_warmup),
+        preempt_rate=np.float32(cfg.preempt_rate),
+        preempt_scale=np.float32(_ticks(cfg.preempt_scale_us)),
+        churn_rate=np.float32(cfg.churn_rate),
+        churn_period=np.int32(max(_ticks(cfg.churn_period_us), 1)),
+        straggle_rate=np.float32(cfg.straggle_rate),
+        straggle_scale=np.float32(cfg.straggle_scale),
+        ks_keys=np.int32(cfg.n_keys),
+        ks_theta=np.float32(ks_theta),
+        ks_zeta=np.float32(ks_zeta),
+        ks_eta=np.float32(ks_eta),
+        ks_alpha=np.float32(ks_alpha),
+        ks_locks=np.int32(cfg.n_locks),
+        hist_warmup=np.int32(cfg.hist_warmup),
         pol=pol_params)
 
 
@@ -1332,8 +1334,16 @@ def sweep_log() -> list:
     return calllog.records()
 
 
-def _n_leaves(*trees) -> int:
-    return len(jax.tree.leaves(trees))
+def _place(inputs, where=None):
+    """Put a call's host inputs ``(tb, pm, windows0)`` on the device in one
+    transfer (onto ``where``, a sharding, when given) and count the placed
+    leaves in the open call's record.  Inputs that hold a tracer (a traced
+    ``slo_us`` or ``seed`` of :func:`run`) are left to the caller's trace."""
+    leaves = jax.tree.leaves(inputs)
+    calllog.current()["arrays"] = len(leaves)
+    if any(isinstance(x, jax.core.Tracer) for x in leaves):
+        return inputs
+    return jax.device_put(inputs, where)
 
 
 def run(cfg: SimConfig, slo_us, seed=0, windows0=None) -> SimState:
@@ -1342,10 +1352,14 @@ def run(cfg: SimConfig, slo_us, seed=0, windows0=None) -> SimState:
     pass a fresh array (reuse the returned ``state.window`` instead)."""
     with calllog.call("run") as rec:
         with calllog.span("build"):
-            tb = build_tables(cfg)
-            pm = build_params(cfg, slo_us, seed)
-            w0 = _default_windows(cfg) if windows0 is None else \
-                jnp.asarray(windows0, jnp.float32)
+            if windows0 is None:
+                w0 = _default_windows(cfg)
+            elif isinstance(windows0, jax.Array):   # donated as it is
+                w0 = jnp.asarray(windows0, jnp.float32)
+            else:
+                w0 = np.asarray(windows0, np.float32)
+            tb, pm, w0 = _place(
+                (build_tables(cfg), build_params(cfg, slo_us, seed), w0))
             ccfg = _canon(cfg)
         # The first call of a program compiles inside its dispatch.
         with calllog.span("dispatch"):
@@ -1354,8 +1368,7 @@ def run(cfg: SimConfig, slo_us, seed=0, windows0=None) -> SimState:
             hit = _run_single._cache_size() == n0
         if not hit:
             _RUN_EXES[ccfg] = next(_EXE_ORDER)
-        rec.update(exe=_RUN_EXES.get(ccfg), hit=hit,
-                   arrays=_n_leaves(tb, pm) + 1)
+        rec.update(exe=_RUN_EXES.get(ccfg), hit=hit)
     return st
 
 
@@ -1457,29 +1470,29 @@ def _cell_params(cfg: SimConfig, cell: dict, slo_us, seed) -> SimParams:
                       cell.get("seed", seed),
                       n_active=cell.get("n_cores", cfg.n_cores))
     if "policy" in cell:
-        pm = pm._replace(pol_id=jnp.int32(POLICIES[cell["policy"]]))
+        pm = pm._replace(pol_id=np.int32(POLICIES[cell["policy"]]))
     if "sim_time_us" in cell:
-        pm = pm._replace(horizon=jnp.int32(_ticks(cell["sim_time_us"])))
+        pm = pm._replace(horizon=np.int32(_ticks(cell["sim_time_us"])))
     if "w_big" in cell:
-        pm = pm._replace(w_big=jnp.float32(cell["w_big"]))
+        pm = pm._replace(w_big=np.float32(cell["w_big"]))
     if "prop_n" in cell:
-        pm = pm._replace(prop_n=jnp.int32(cell["prop_n"]))
+        pm = pm._replace(prop_n=np.int32(cell["prop_n"]))
     if "long_epoch_prob" in cell:
-        pm = pm._replace(long_prob=jnp.float32(cell["long_epoch_prob"]))
+        pm = pm._replace(long_prob=np.float32(cell["long_epoch_prob"]))
     if "long_epoch_scale" in cell:
-        pm = pm._replace(long_scale=jnp.float32(cell["long_epoch_scale"]))
+        pm = pm._replace(long_scale=np.float32(cell["long_epoch_scale"]))
     if "wakeup_us" in cell:
-        pm = pm._replace(wakeup=jnp.int32(_ticks(cell["wakeup_us"])))
+        pm = pm._replace(wakeup=np.int32(_ticks(cell["wakeup_us"])))
     for axis in _WL_AXES:
         if axis in cell:
             pm = pm._replace(
-                **{_PARAM_AXES[axis]: jnp.float32(cell[axis])})
+                **{_PARAM_AXES[axis]: np.float32(cell[axis])})
     for axis in ("preempt_rate", "churn_rate", "straggle_rate",
                  "straggle_scale"):
         if axis in cell:
-            pm = pm._replace(**{axis: jnp.float32(cell[axis])})
+            pm = pm._replace(**{axis: np.float32(cell[axis])})
     if "preempt_scale" in cell:
-        pm = pm._replace(preempt_scale=jnp.float32(
+        pm = pm._replace(preempt_scale=np.float32(
             _ticks(cell["preempt_scale"])))
     if any(a in cell for a in _KS_AXES):
         # n_keys / zipf_theta change the Zipf sampler constants, which
@@ -1490,21 +1503,21 @@ def _cell_params(cfg: SimConfig, cell: dict, slo_us, seed) -> SimParams:
         th = float(cell.get("zipf_theta", cfg.zipf_theta))
         ks_th, ks_ze, ks_et, ks_al = wlk.zipf_consts(max(nk, 1), th)
         pm = pm._replace(
-            ks_keys=jnp.int32(nk), ks_theta=jnp.float32(ks_th),
-            ks_zeta=jnp.float32(ks_ze), ks_eta=jnp.float32(ks_et),
-            ks_alpha=jnp.float32(ks_al),
-            ks_locks=jnp.int32(cell.get("n_locks", cfg.n_locks)))
+            ks_keys=np.int32(nk), ks_theta=np.float32(ks_th),
+            ks_zeta=np.float32(ks_ze), ks_eta=np.float32(ks_et),
+            ks_alpha=np.float32(ks_al),
+            ks_locks=np.int32(cell.get("n_locks", cfg.n_locks)))
     if "window0_us" in cell:
         # A swept initial window plays the role of default_window_us (the
         # seed's LibASL-MAX cells set both), so the unit floor follows it.
-        pm = pm._replace(unit0=jnp.float32(
+        pm = pm._replace(unit0=np.float32(
             aimd.unit_for(_ticks(cell["window0_us"]), cfg.pct)))
     # Policy-declared axes land in the traced SimParams.pol slots (the
     # built-in fields above are already covered by _PARAM_AXES).
     for axis, slot in _active_policy(cfg).sweep_axes.items():
         if axis in cell and slot in pm.pol:
             pm = pm._replace(pol=dict(pm.pol, **{
-                slot: jnp.asarray(cell[axis], pm.pol[slot].dtype)}))
+                slot: np.asarray(cell[axis], pm.pol[slot].dtype)}))
     return pm
 
 
@@ -1561,18 +1574,15 @@ def _sweep_resumable(ccfg: SimConfig, tb: SimTables, pm: SimParams, w0,
     parts = []
     for k, (lo, hi) in enumerate(bounds):
         if done is not None and k <= done:
-            tb_k = jax.tree.map(lambda x: x[lo:hi], tb)
-            pm_k = jax.tree.map(lambda x: x[lo:hi], pm)
-            target = jax.eval_shape(_batched(ccfg), tb_k, pm_k, w0[lo:hi])
+            target = jax.eval_shape(_batched(ccfg), *jax.tree.map(
+                lambda x: x[lo:hi], (tb, pm, w0)))
             parts.append(ckpt.restore(d, k, target))
             continue
         rec = calllog.current() or calls.enter_context(calllog.call("sweep"))
         with calllog.span("build"):
-            tb_k = jax.tree.map(lambda x: x[lo:hi], tb)
-            pm_k = jax.tree.map(lambda x: x[lo:hi], pm)
-            w_k = w0[lo:hi]
+            tb_k, pm_k, w_k = _place(
+                jax.tree.map(lambda x: x[lo:hi], (tb, pm, w0)))
         rec["lanes"] = hi - lo
-        rec["arrays"] += _n_leaves(tb_k, pm_k) + 1
         st_k = _call_batch(ccfg, tb_k, pm_k, w_k)
         calls.close()                   # the slice's record joins the log
         ckpt.save(d, k, st_k)
@@ -1583,12 +1593,14 @@ def _sweep_resumable(ccfg: SimConfig, tb: SimTables, pm: SimParams, w0,
 def _sweep_inputs(cfg: SimConfig, axes: dict, *, slo_us=1e9, seed=0,
                   windows0=None, product: bool = True, mesh=None,
                   data_axis="data"):
-    """Validate a :func:`sweep` grid and build its traced inputs.
+    """Validate a :func:`sweep` grid and build its traced inputs on the host.
 
-    Returns ``(cfg, cells, tb, pm, w0)``: the template with every gate the
-    axes need switched on (its ``_canon`` is the executable's key), one
-    dict per cell, and the stacked per-cell tables, params and initial
-    windows — padded and placed on ``mesh`` when one is given."""
+    Returns ``(cfg, cells, tb, pm, w0, where)``: the template with every
+    gate the axes need switched on (its ``_canon`` is the executable's key),
+    one dict per cell, the stacked per-cell tables, params and initial
+    windows as numpy arrays (padded to the shard count on ``mesh``), and
+    the sharding they go to: the cells' ``NamedSharding`` on ``mesh``, else
+    None (the default device)."""
     if not axes:
         raise ValueError("empty sweep: pass at least one axis")
     # A "policy" axis merges its values into ONE multi-policy
@@ -1673,14 +1685,14 @@ def _sweep_inputs(cfg: SimConfig, axes: dict, *, slo_us=1e9, seed=0,
     if table_keys:
         tbs = [build_tables(_cell_tables_cfg(cfg, cell, table_keys))
                for cell in cells]
-        tb = jax.tree.map(lambda *xs: jnp.stack(xs), *tbs)
+        tb = jax.tree.map(lambda *xs: np.stack(xs), *tbs)
     else:
         tb1 = build_tables(cfg)
         tb = jax.tree.map(
-            lambda x: jnp.broadcast_to(x, (len(cells),) + x.shape), tb1)
+            lambda x: np.broadcast_to(x, (len(cells),) + np.shape(x)), tb1)
 
     pms = [_cell_params(cfg, cell, slo_us, seed) for cell in cells]
-    pm = jax.tree.map(lambda *xs: jnp.stack(xs), *pms)
+    pm = jax.tree.map(lambda *xs: np.stack(xs), *pms)
 
     base_w = _default_windows(cfg) if windows0 is None else \
         np.asarray(windows0, np.float32)
@@ -1688,40 +1700,20 @@ def _sweep_inputs(cfg: SimConfig, axes: dict, *, slo_us=1e9, seed=0,
         np.full(cfg.n_cores, _ticks(cell["window0_us"]), np.float32)
         if "window0_us" in cell else base_w for cell in cells])
 
-    n_cells, pad = len(cells), 0
+    where = None
     if mesh is not None:
         from repro.dist.sharding import build_sweep_rules
         from jax.sharding import NamedSharding
         rules = build_sweep_rules(mesh, data_axis=data_axis)
-        n_shards = rules.num_shards("cells")
-        pad = (-n_cells) % n_shards
+        pad = (-len(cells)) % rules.num_shards("cells")
         if pad:  # equal row splits: duplicate the last cell (sweep trims)
-            rep = partial(jnp.repeat, repeats=pad, axis=0)
-            tb = jax.tree.map(lambda x: jnp.concatenate([x, rep(x[-1:])]),
-                              tb)
-            pm = jax.tree.map(lambda x: jnp.concatenate([x, rep(x[-1:])]),
-                              pm)
-            w0 = np.concatenate([w0, np.repeat(w0[-1:], pad, axis=0)])
-        ns = NamedSharding(mesh, rules.spec(("cells",), (n_cells + pad,)))
-        tb, pm = jax.device_put((tb, pm), ns)
-        w0 = jax.device_put(w0, ns)
+            tb, pm, w0 = jax.tree.map(
+                lambda x: np.concatenate([x, np.repeat(x[-1:], pad, 0)]),
+                (tb, pm, w0))
+        where = NamedSharding(mesh, rules.spec(("cells",),
+                                               (len(cells) + pad,)))
 
-    return cfg, cells, tb, pm, w0
-
-
-def _n_input_arrays(axes: dict, n_cells: int, tb: SimTables,
-                    pm: SimParams, mesh) -> int:
-    """Device arrays a sweep's input build makes, counted from one cell's
-    leaves: every cell's params (and tables, when a table axis is swept;
-    else one set), the stacked leaves, the windows, and on a mesh the
-    placed (and padded) copies."""
-    n_tb, n_pm = _n_leaves(tb), _n_leaves(pm)
-    tables = n_cells if any(k in table_axes() for k in axes) else 1
-    n = n_cells * n_pm + tables * n_tb + n_pm + n_tb + 1
-    if mesh is not None:
-        padded = np.shape(pm.slo)[0] > n_cells
-        n += (1 + padded) * (n_tb + n_pm + 1)
-    return n
+    return cfg, cells, tb, pm, w0, where
 
 
 def sweep(cfg: SimConfig, axes: dict, *, slo_us=1e9, seed=0,
@@ -1762,12 +1754,13 @@ def sweep(cfg: SimConfig, axes: dict, *, slo_us=1e9, seed=0,
     with contextlib.ExitStack() as calls:
         rec = calls.enter_context(calllog.call("sweep"))
         with calllog.span("build"):
-            cfg, cells, tb, pm, w0 = _sweep_inputs(
+            cfg, cells, tb, pm, w0, where = _sweep_inputs(
                 cfg, axes, slo_us=slo_us, seed=seed, windows0=windows0,
                 product=product, mesh=mesh, data_axis=data_axis)
+            if resume_dir is None:      # the resumable path places slices
+                tb, pm, w0 = _place((tb, pm, w0), where)
         n_cells = len(cells)
         rec["lanes"] = n_cells
-        rec["arrays"] = _n_input_arrays(axes, n_cells, tb, pm, mesh)
         if resume_dir is not None:
             st = _sweep_resumable(_canon(cfg), tb, pm, w0, resume_dir,
                                   resume_chunk, calls)

@@ -5,6 +5,7 @@ count; results bit-identical whether or not a profiler session runs."""
 import glob
 import sys
 import threading
+import time
 from pathlib import Path
 
 import jax
@@ -69,8 +70,8 @@ def test_sweep_record_counts_its_input_arrays():
     (st, _), (rec, _) = _new_records(_sweep_job)
     tb, pm = sl.build_tables(CFG), sl.build_params(CFG, 50.0)
     n_tb, n_pm = len(jax.tree.leaves(tb)), len(jax.tree.leaves(pm))
-    # three cells' params, one table set, both stacked, the windows
-    assert rec["arrays"] == 3 * n_pm + n_tb + n_pm + n_tb + 1
+    # one placement of the stacked tables, the stacked params, the windows
+    assert rec["arrays"] == n_tb + n_pm + 1
     assert rec["n_cells"] == 3 and rec["devices"] == 1
     assert rec["collectives"]["total_count"] == 0
     assert "flops" not in rec and "bytes_accessed" not in rec
@@ -119,22 +120,37 @@ def test_summaries_of_host_state_log_nothing():
     assert recs == []
 
 
-def test_resumable_sweep_logs_one_record_per_computed_slice(tmp_path):
+def test_resumable_sweep_logs_one_record_per_computed_slice(tmp_path,
+                                                           monkeypatch):
     axes = {"n_cores": [1, 2, 3, 4, 5]}
     want, _ = sl.sweep(CFG, axes, slo_us=50.0)
+    # the grid's host build takes a known 0.1 s, so where it lands shows
+    grid_build = sl._sweep_inputs
+
+    def slow_grid_build(*args, **kw):
+        time.sleep(0.1)
+        return grid_build(*args, **kw)
+
+    monkeypatch.setattr(sl, "_sweep_inputs", slow_grid_build)
     (st, _), recs = _new_records(lambda: sl.sweep(
         CFG, axes, slo_us=50.0, resume_dir=tmp_path, resume_chunk=2))
     assert [r["lanes"] for r in recs] == [2, 2, 1]
     assert all(r["kind"] == "sweep" and r["phases"]["dispatch"] > 0.0
                for r in recs)
     # the first record carries the whole grid's input build
-    assert recs[0]["phases"]["build"] > recs[1]["phases"]["build"]
+    assert recs[0]["phases"]["build"] >= 0.1
+    assert all(r["phases"]["build"] < 0.1 for r in recs[1:])
+    # each computed slice places its own inputs once
+    n = len(jax.tree.leaves((sl.build_tables(CFG),
+                             sl.build_params(CFG, 50.0)))) + 1
+    assert [r["arrays"] for r in recs] == [n, n, n]
     for x, y in zip(jax.tree.leaves(want), jax.tree.leaves(st)):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
     # a re-run restores every slice and logs the build alone
     _, again = _new_records(lambda: sl.sweep(
         CFG, axes, slo_us=50.0, resume_dir=tmp_path, resume_chunk=2))
     assert len(again) == 1 and again[0]["phases"]["dispatch"] == 0.0
+    assert again[0]["arrays"] == 0
 
 
 def test_spans_nest_inside_an_enclosing_annotation_in_a_trace(tmp_path):

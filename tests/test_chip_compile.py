@@ -57,7 +57,7 @@ def _shapes(tree, sharding):
 
 
 def _compile_sweep(cfg, axes, sharding, slo_us=1e9):
-    cfg, cells, tb, pm, w0 = sl._sweep_inputs(cfg, axes, slo_us=slo_us)
+    cfg, cells, tb, pm, w0, _ = sl._sweep_inputs(cfg, axes, slo_us=slo_us)
     args = _shapes((tb, pm, w0), sharding)
     return jax.jit(sl._batched(sl._canon(cfg))).lower(*args).compile(), \
         len(cells)
