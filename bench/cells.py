@@ -2,8 +2,15 @@
 the names ``BENCHMARK.json`` gives them, turned into the calls a job makes.
 
 A configuration (``configs/<name>.json``) states the machine, the epoch
-program and the lock policies' knobs; the generator models closed-loop
-epochs only.  A traffic mix
+program and the lock policies' knobs.  The generator takes closed-loop
+epochs, keyed or not, and refuses open-loop arrivals (no reference models
+them yet).  Without a ``keys`` block every epoch contends the locks of its
+static segment program (``epoch.seg_lock``).  With ``"keys": {"n_keys": K,
+"zipf_theta": theta}`` each epoch of each core draws a key from YCSB's
+Zipf(K, theta) and contends the bucket lock ``key % epoch.n_locks``, and
+``ks_crew`` draws the epoch's read/write class besides; the block is
+refused where ``K < n_locks``, where theta is negative or not finite, and
+beside open-loop arrivals.  A traffic mix
 (``traffic/<name>.json``) states the grid: which policies, the grid axes,
 the horizon, how many seed replicas per grid point, whether a job is
 ``sweep`` calls or one ``run``, and how much of each job the correctness
@@ -21,6 +28,7 @@ import dataclasses
 import hashlib
 import itertools
 import json
+import math
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parent
@@ -75,6 +83,7 @@ class Plan:
             raise ValueError(f"config {config['name']!r} asks for open-loop "
                              "arrivals; the generator and the reference "
                              "model closed-loop epochs only")
+        self.keys = _keys(config)
         self.kind = traffic["job"]
         if self.kind not in ("sweep", "run"):
             raise ValueError(f"job kind {self.kind!r} is not sweep or run")
@@ -133,6 +142,7 @@ class Plan:
                     epcap=lk["epcap"], max_events=lk["max_events"])
                 for k in LANE_KNOBS + POLICY_KNOBS:
                     lane[k] = self.knob(pol, k)
+                lane.update(self.keys)
                 out.append(lane)
         return out
 
@@ -159,7 +169,7 @@ class Plan:
             max_window_us=lk["max_window_us"], pct=lk["pct"],
             epcap=lk["epcap"], max_events=lk["max_events"],
             sim_time_us=self.sim_time_us,
-            policy_kw=tuple(sorted(kw.items())))
+            policy_kw=tuple(sorted(kw.items())), **self.keys)
 
     def axes(self, call: Call, lanes: list) -> dict:
         """Zipped sweep axes, one entry per lane."""
@@ -174,6 +184,31 @@ class Plan:
         if self.replicas:
             ax["seed"] = [ln["seed"] for ln in lanes]
         return ax
+
+
+def _keys(config: dict) -> dict:
+    """The configuration's ``keys`` block as ``n_keys`` and ``zipf_theta``
+    for the program and the reference; empty for a key-off configuration.
+    Refuses a block no run could honour."""
+    keys = config.get("keys")
+    if keys is None:
+        return {}
+    name = config["name"]
+    if set(keys) != {"n_keys", "zipf_theta"}:
+        raise ValueError(f"config {name!r}: the keys block holds exactly "
+                         f"n_keys and zipf_theta, not {sorted(keys)}")
+    n_keys, theta = keys["n_keys"], keys["zipf_theta"]
+    n_locks = config["epoch"]["n_locks"]
+    if isinstance(n_keys, bool) or not isinstance(n_keys, int) \
+            or n_keys < n_locks:
+        raise ValueError(f"config {name!r}: n_keys {n_keys!r} is not a "
+                         f"whole number of at least n_locks ({n_locks}); "
+                         "every bucket lock needs a key")
+    if isinstance(theta, bool) or not isinstance(theta, (int, float)) \
+            or not math.isfinite(theta) or theta < 0:
+        raise ValueError(f"config {name!r}: zipf_theta {theta!r} is not a "
+                         "finite number of at least 0")
+    return {"n_keys": n_keys, "zipf_theta": float(theta)}
 
 
 _OWNER = {"shfl": ("shfl_bound",), "dvfs_race": ("race_bound",),

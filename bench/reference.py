@@ -4,14 +4,25 @@ A straightforward sequential discrete-event loop, one sweep lane at a time,
 written from the semantics the configuration files state and imports nothing
 of the program under test.  Time is integer ticks (1 tick = 10 ns), exactly
 as the program keeps it; the few float quantities (the LibASL reorder
-window and its AIMD unit, the TAS draw) are computed in ``dtype``: float32
-for the reference, bfloat16 for the control (``CONTROL_DTYPE``).  Epochs are
-closed-loop: a core starts its next epoch when the last one ends.
+window and its AIMD unit, the TAS draw, the Zipf key and read/write draws)
+are computed in ``dtype``: float32 for the reference, bfloat16 for the
+control (``CONTROL_DTYPE``).  Epochs are closed-loop: a core starts its next
+epoch when the last one ends.
 
 Random draws are threefry draws from ``jax.random`` (the PRNG that defines
-the configuration's traffic); LibASL's window halving is evaluated with
-``jax.numpy`` on ``device``, so that a chip run compares against the chip's
-own float arithmetic (a TPU flushes subnormals to zero).
+the configuration's traffic); LibASL's window halving and the Zipf inverse
+CDF are evaluated with ``jax.numpy`` on ``device``, so that a chip run
+compares against the chip's own float arithmetic (a TPU flushes subnormals
+to zero, and its ``pow`` may round otherwise than the CPU's).
+
+A lane with ``n_keys`` draws, for each core and epoch, a key from YCSB's
+Zipf(``n_keys``, ``zipf_theta``) (Gray et al.'s inverse CDF, as YCSB's
+``ZipfianGenerator`` computes it) and contends the bucket lock
+``key % n_locks``; ``ks_crew`` lanes also draw the epoch's read/write
+uniform.  Both are counter draws: ``uniform(fold_in(fold_in(fold_in(
+PRNGKey(seed), stream), core), epoch))`` with the streams ``STREAM_KEY`` and
+``STREAM_RW``.  Epoch 0 is drawn for every core at the start, the next
+epoch's at each epoch's end.
 
 Per lane the reference returns every leaf of the simulator's final state and
 the host summary built from it (``summarize``); ``leaves_differing`` and
@@ -36,6 +47,9 @@ POL_SLOTS = {"shfl": "shfl_ctr", "dvfs_race": "race_ctr",
              "ks_jbsq": "jbsq_ctr"}
 CONTROL_DTYPE = ml_dtypes.bfloat16
 BLOCK = 1024                       # draws computed per jax call
+STREAM_KEY, STREAM_RW = 0x778A, 0x778B
+RW_POLICIES = ("ks_crew",)         # policies whose epochs read or write
+ZIPF_POLE_EPS = 1e-4               # theta this close to 1 moves off the pole
 
 
 def ticks(us) -> int:
@@ -90,6 +104,79 @@ class KeyChain:
 
 def _CPU():
     return jax.devices("cpu")[0]
+
+
+def zipf_consts(n_keys: int, theta: float) -> np.ndarray:
+    """Gray et al.'s constants of Zipf(``n_keys``, ``theta``) over ranks
+    1..n, computed in float64 and kept as float32: ``(n, theta', zeta,
+    eta, alpha)`` with ``theta'`` moved ``ZIPF_POLE_EPS`` off the pole of
+    ``alpha = 1 / (1 - theta)``, ``zeta`` the harmonic number
+    H(n, theta') and ``eta = (1 - (2/n)**(1 - theta')) / (1 - zeta(2) /
+    zeta)`` (1 where n <= 2, whose tail is never reached)."""
+    if abs(theta - 1.0) < ZIPF_POLE_EPS:
+        theta = 1.0 - ZIPF_POLE_EPS if theta <= 1.0 else 1.0 + ZIPF_POLE_EPS
+    ranks = np.arange(1, n_keys + 1, dtype=np.float64)
+    zeta = float(np.sum(ranks ** -theta))
+    zeta2 = 1.0 + 0.5 ** theta if n_keys >= 2 else zeta
+    denom = 1.0 - zeta2 / zeta
+    eta = (1.0 - (2.0 / n_keys) ** (1.0 - theta)) / denom \
+        if n_keys > 2 and abs(denom) > 1e-12 else 1.0
+    return np.asarray([n_keys, theta, zeta, eta, 1.0 / (1.0 - theta)],
+                      np.float32)
+
+
+@partial(jax.jit, static_argnums=(5, 6))
+def _epoch_draws(key_stream, rw_stream, core, e0, consts, dtype, rw):
+    """Key ranks and read/write uniforms of epochs ``e0 .. e0 + BLOCK - 1``
+    of one core, from the streams' base keys.  The ranks follow Gray et
+    al.'s inverse CDF in ``dtype``: rank 0 below 1/zeta, rank 1 below
+    zeta(2)/zeta, else floor(n (eta u - eta + 1)**alpha), clipped to
+    [0, n).  The uniforms are None where ``rw`` is off."""
+    es = e0 + jnp.arange(BLOCK, dtype=jnp.int32)
+
+    def uniforms(stream):
+        base = jax.random.fold_in(stream, core)
+        return jax.vmap(lambda e: jax.random.uniform(
+            jax.random.fold_in(base, e)))(es)
+    n, theta, zeta, eta, alpha = (consts[i].astype(dtype) for i in range(5))
+    u = uniforms(key_stream).astype(dtype)
+    uz = u * zeta
+    zeta2 = 1.0 + 0.5 ** theta
+    tail = jnp.floor(n * (eta * u - eta + 1.0) ** alpha)
+    k = jnp.where(uz < 1.0, 0.0, jnp.where(uz < zeta2, 1.0, tail))
+    ranks = jnp.clip(k, 0.0, n - 1.0).astype(jnp.int32)
+    return ranks, uniforms(rw_stream) if rw else None
+
+
+class EpochDraws:
+    """The per-(core, epoch) key-drawn lock and read/write uniform of one
+    keyed lane, computed ``BLOCK`` epochs of a core per device call."""
+
+    def __init__(self, p, dtype, device):
+        self.F, self.device = dtype, device
+        self.consts = zipf_consts(p["n_keys"], p["zipf_theta"])
+        self.n_locks = p["n_locks"]
+        self.rw = p["policy"] in RW_POLICIES
+        base = jax.random.PRNGKey(p["seed"])
+        self.streams = [np.asarray(jax.random.fold_in(base, s))
+                        for s in (STREAM_KEY, STREAM_RW)]
+        self.blocks = {}
+
+    def __call__(self, c: int, e: int):
+        """(lock, read/write uniform) of core ``c``'s epoch ``e``; the
+        uniform is 1.0, a read, where the policy has no read/write
+        stream."""
+        b = e // BLOCK
+        if (c, b) not in self.blocks:
+            with jax.default_device(self.device):
+                ranks, rw = _epoch_draws(*self.streams, np.int32(c),
+                                         np.int32(b * BLOCK), self.consts,
+                                         self.F, self.rw)
+            self.blocks[c, b] = (np.asarray(ranks) % self.n_locks,
+                                 None if rw is None else np.asarray(rw))
+        locks, rws = self.blocks[c, b]
+        i = e % BLOCK
+        return int(locks[i]), self.F(1.0) if rws is None else self.F(rws[i])
 
 
 @partial(jax.jit, static_argnums=(1, 2))
@@ -165,9 +252,21 @@ class Lane:
         self.phase = [NONCRIT] * n
         self.t_ready = [self.nc_dur[c][0] + c if self.active[c] else INF
                         for c in range(n)]
+        # Keyed lanes: every core's epoch-0 lock and read/write uniform,
+        # padded cores included.
+        self.draws = EpochDraws(p, F, self.device) if p.get("n_keys") \
+            else None
+        first = [self.draws(c, 0) if self.draws else (0, F(1.0))
+                 for c in range(n)]
+        self.cur_lock = [lk for lk, _ in first]
+        self.cur_rw = [rw for _, rw in first]
 
     # -- helpers ----------------------------------------------------------
     def lock(self, c):
+        """The lock core ``c`` contends: its epoch's drawn bucket lock on a
+        keyed lane, else its segment's."""
+        if self.draws:
+            return self.cur_lock[c]
         return self.seg_lock[self.seg[c]]
 
     def waiting(self, l, phase=QUEUED):
@@ -266,6 +365,9 @@ class Lane:
             self.ep_cnt[c] += 1
             if self.pol == "libasl" and not self.big[c]:
                 self.aimd(c, F(np.float32(ep_latency)))
+            if self.draws:             # the next epoch's lock; l is kept
+                self.cur_lock[c], self.cur_rw[c] = self.draws(
+                    c, self.ep_cnt[c])
         if last:
             self.epoch_start[c] = t + self.inter[c]
             self.t_ready[c] = t + self.inter[c] + self.nc_dur[c][0]
@@ -354,8 +456,14 @@ class Lane:
             if pol == "ks_erew":
                 use, prefer = waiting[owner], owner
                 bound = p["erew_bound"]
-            elif pol == "ks_crew":      # every epoch reads: keys are off
-                use, prefer = True, head
+            elif pol == "ks_crew":
+                # readers first, the earliest; else the owner's write
+                writer = [self.cur_rw[c] < self.F(np.float32(
+                    p["crew_wfrac"])) for c in range(n)]
+                readers = [waiting[c] and not writer[c] for c in range(n)]
+                owner_writes = waiting[owner] and writer[owner]
+                use = any(readers) or owner_writes
+                prefer = self.head_of(readers) if any(readers) else owner
                 bound = p["crew_bound"]
             else:                       # ks_jbsq: the least served waiter
                 served = min(self.ep_cnt[c] for c in range(n) if waiting[c])
@@ -419,8 +527,8 @@ class Lane:
             "events": np.int32(self.events),
             "arr_t": np.zeros(n, np.int32),
             "energy": np.zeros(n, f32),
-            "cur_lock": np.zeros(n, np.int32),
-            "cur_rw": np.ones(n, f32),
+            "cur_lock": np.asarray(self.cur_lock, np.int32),
+            "cur_rw": np.asarray(self.cur_rw, f32),
             "ep_hist": np.zeros((n, 1), np.uint32),
             "cs_hist": np.zeros((n, 1), np.uint32),
         }
