@@ -5,6 +5,8 @@ slice on the resumable path), ``run``, ``sweep_summaries`` and
 ``summarize`` of device state, and times its phases as spans:
 
 * ``build``: the host input build and its one placement on the device;
+* ``keys``: within ``build``, the Zipf sampler's host constants of a keyed
+  call (``SimConfig.n_keys > 0``);
 * ``compile``: a batched executable's lower, compile (or persistent-cache
   load) and accounting, on a cache miss;
 * ``dispatch``: the executable's lookup and asynchronous call;
@@ -18,7 +20,9 @@ clock.  A phase's seconds are its own: a span nested in another (a
 compile inside a dispatch) is not counted twice.  One ``jax.monitoring``
 listener adds the seconds JAX spends tracing, lowering and compiling (a
 persistent-cache load included) to the call whose span is innermost on the
-compiling thread; compiles outside any span are not counted.  With no
+compiling thread, in all (``compile_s``) and by event (``trace_s``,
+``lower_s``, ``backend_s``; ``cache_load_s``, the cache loads within
+``backend_s``); compiles outside any span are not counted.  With no
 profiler session running a span costs about a microsecond; nothing is
 switched on or off.
 """
@@ -38,10 +42,17 @@ PHASES = {"sweep": ("build", "compile", "dispatch"),
           "run": ("build", "dispatch"),
           "sweep_summaries": ("wait", "transfer", "reduce"),
           "summarize": ("wait", "transfer", "reduce")}
-COMPILE_EVENTS = frozenset((
-    "/jax/core/compile/jaxpr_trace_duration",
-    "/jax/core/compile/jaxpr_to_mlir_module_duration",
-    "/jax/core/compile/backend_compile_duration"))
+# JAX's compile events and the record field each one's seconds go to; the
+# first three make up ``compile_s``.  A persistent-cache load happens
+# inside a backend compile event, so ``cache_load_s`` is part of
+# ``backend_s``.
+COMPILE_SPLIT = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/core/compile/backend_compile_duration": "backend_s",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load_s"}
+COMPILE_EVENTS = frozenset(e for e, f in COMPILE_SPLIT.items()
+                           if f != "cache_load_s")
 
 _LOG: list = []
 _SEQ = itertools.count()
@@ -69,11 +80,13 @@ def call(kind: str, **fields):
     (a call that raises leaves none).  Yields the record, a fresh dict:
     ``kind``, ``lanes``, ``exe`` (the executable's index in compile
     order), ``hit`` (no compile was needed), ``phases`` (seconds per
-    span), ``compile_s``, ``arrays`` (leaves the input build placed),
-    then ``seq`` (the call's place in the process) once logged."""
+    span), ``compile_s`` and its split by JAX event (``trace_s``,
+    ``lower_s``, ``backend_s``, ``cache_load_s``), ``arrays`` (leaves the
+    input build placed), then ``seq`` (the call's place in the process)
+    once logged."""
     rec = {"kind": kind, "lanes": 1, "exe": None, "hit": None,
            "phases": dict.fromkeys(PHASES[kind], 0.0), "compile_s": 0.0,
-           "arrays": 0}
+           **dict.fromkeys(COMPILE_SPLIT.values(), 0.0), "arrays": 0}
     rec.update(fields)
     c = _Call(rec)
     calls = _TLS.calls
@@ -82,7 +95,10 @@ def call(kind: str, **fields):
         yield rec
     finally:
         calls.pop()
-    rec["compile_s"] = _covered(c.compiles)
+    rec["compile_s"] = _covered(iv for e, *iv in c.compiles
+                                if e in COMPILE_EVENTS)
+    for event, field in COMPILE_SPLIT.items():
+        rec[field] = _covered(iv for e, *iv in c.compiles if e == event)
     with LOCK:
         rec["seq"] = next(_SEQ)
         _LOG.append(rec)
@@ -146,11 +162,11 @@ def _covered(intervals) -> float:
 
 
 def _on_duration(event: str, duration: float, **_) -> None:
-    if event in COMPILE_EVENTS:
+    if event in COMPILE_SPLIT:
         spans = _TLS.spans
         if spans and spans[-1].call is not None:
             end = time.perf_counter()
-            spans[-1].call.compiles.append((end - duration, end))
+            spans[-1].call.compiles.append((event, end - duration, end))
 
 
 jax.monitoring.register_event_duration_secs_listener(_on_duration)

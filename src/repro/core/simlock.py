@@ -152,6 +152,9 @@ def _validate_config(cfg) -> None:
     if not math.isfinite(cfg.zipf_theta) or cfg.zipf_theta < 0.0:
         raise ValueError("SimConfig.zipf_theta must be finite and >= 0, "
                          f"got {cfg.zipf_theta!r}")
+    if cfg.n_keys > wlk.MAX_KEYS:
+        raise ValueError(f"SimConfig.n_keys={cfg.n_keys} exceeds 2**24: "
+                         f"{wlk.TOO_MANY_KEYS}")
     if 0 < cfg.n_keys < cfg.n_locks:
         raise ValueError(
             f"SimConfig.n_keys={cfg.n_keys} is smaller than "
@@ -692,8 +695,8 @@ def build_params(cfg: SimConfig, slo_us, seed=0, n_active=None) -> SimParams:
             f"{cfg.policy!r}; known knobs: {sorted(pol_params)}")
     slo = (slo_us * US).astype(np.float32) if hasattr(slo_us, "astype") \
         else np.float32(_ticks(slo_us))
-    ks_theta, ks_zeta, ks_eta, ks_alpha = wlk.zipf_consts(
-        max(cfg.n_keys, 1), cfg.zipf_theta)
+    ks_theta, ks_zeta, ks_eta, ks_alpha = _zipf_consts(cfg.n_keys,
+                                                       cfg.zipf_theta)
     return SimParams(
         slo=slo,
         pol_id=np.int32(POLICIES[cfg.policy]),
@@ -734,6 +737,15 @@ def build_params(cfg: SimConfig, slo_us, seed=0, n_active=None) -> SimParams:
         ks_locks=np.int32(cfg.n_locks),
         hist_warmup=np.int32(cfg.hist_warmup),
         pol=pol_params)
+
+
+def _zipf_consts(n_keys: int, theta: float):
+    """The Zipf sampler's host constants; with keys on, inside the open
+    call's ``keys`` span (key-off params carry those of one key)."""
+    if n_keys < 1:
+        return wlk.zipf_consts(1, theta)
+    with calllog.span("keys"):
+        return wlk.zipf_consts(n_keys, theta)
 
 
 def _default_windows(cfg: SimConfig) -> np.ndarray:
@@ -1501,7 +1513,7 @@ def _cell_params(cfg: SimConfig, cell: dict, slo_us, seed) -> SimParams:
         # with what build_params would produce for that config.
         nk = int(cell.get("n_keys", cfg.n_keys))
         th = float(cell.get("zipf_theta", cfg.zipf_theta))
-        ks_th, ks_ze, ks_et, ks_al = wlk.zipf_consts(max(nk, 1), th)
+        ks_th, ks_ze, ks_et, ks_al = _zipf_consts(nk, th)
         pm = pm._replace(
             ks_keys=np.int32(nk), ks_theta=np.float32(ks_th),
             ks_zeta=np.float32(ks_ze), ks_eta=np.float32(ks_et),
@@ -1795,19 +1807,30 @@ def sweep_summaries(cfg: SimConfig, st: SimState, grid: dict,
                 host.events.shape[0]
             rec["lanes"] = n_cells
             names = _summary_leaves(cfg)
-            out = []
+            keyed = _ks_on(cfg) or "n_keys" in grid
+            out, draws = [], 0
             for i in range(n_cells):
                 cell_st = host._replace(
                     **{k: getattr(host, k)[i] for k in names})
                 n_act = int(grid["n_cores"][i]) if "n_cores" in grid \
                     else None
+                if keyed:
+                    draws += _key_draws(cfg, cell_st, n_act)
                 cell_slo = float(grid["slo_us"][i]) if "slo_us" in grid \
                     else slo_us
                 s = summarize(cfg, cell_st, warmup, n_active=n_act,
                               slo_us=cell_slo)
                 s.update({k: grid[k][i] for k in grid})
                 out.append(s)
+            rec["key_draws"] = draws
     return out
+
+
+def _key_draws(cfg: SimConfig, st: SimState, n_active=None) -> int:
+    """Epochs whose key was drawn in one lane's active cores: epoch 0's at
+    the start and one more at each epoch's end, ``ep_cnt + 1`` a core."""
+    n = cfg.n_cores if n_active is None else int(n_active)
+    return int(np.asarray(st.ep_cnt)[:n].sum()) + n
 
 
 def _summary_leaves(cfg: SimConfig) -> tuple:
@@ -1926,9 +1949,11 @@ def summarize(cfg: SimConfig, st: SimState, warmup: int = 32,
     Given device state it logs a call record (:func:`sweep_log`)."""
     if any(isinstance(getattr(st, k), jax.Array)
            for k in _summary_leaves(cfg)):
-        with calllog.call("summarize"):
+        with calllog.call("summarize") as rec:
             st = _to_host(cfg, st)
             with calllog.span("reduce"):
+                rec["key_draws"] = _key_draws(cfg, st, n_active) \
+                    if _ks_on(cfg) else 0
                 return _summary(cfg, st, warmup, n_active, slo_us)
     return _summary(cfg, st, warmup, n_active, slo_us)
 

@@ -45,6 +45,12 @@ from repro.workloads.generators import (STREAM_KEY, STREAM_RW,
 #: Exponents within this distance of the theta=1 pole are nudged off it
 #: (the Gray/YCSB constants divide by ``1 - theta``).
 _POLE_EPS = 1e-4
+#: The most keys a draw can reach: :func:`zipf_key` computes the rank in
+#: float32, which holds every integer up to 2**24 and only even ones above.
+MAX_KEYS = 1 << 24
+TOO_MANY_KEYS = ("the Zipf rank is drawn in float32, whose integers above "
+                 "2**24 are even only, so odd tail ranks could never be "
+                 "drawn and rank % n_locks would skew the buckets")
 
 
 def zipf_consts(n_keys: int, theta: float):
@@ -61,6 +67,9 @@ def zipf_consts(n_keys: int, theta: float):
     theta = float(theta)
     if n_keys < 1:
         raise ValueError(f"zipf_consts: n_keys must be >= 1, got {n_keys}")
+    if n_keys > MAX_KEYS:
+        raise ValueError(f"zipf_consts: n_keys {n_keys} exceeds 2**24: "
+                         f"{TOO_MANY_KEYS}")
     if not np.isfinite(theta) or theta < 0.0:
         raise ValueError("zipf_consts: theta must be finite and >= 0, "
                          f"got {theta!r}")
@@ -141,6 +150,14 @@ def _u_grid(key, n_cores: int, n_epochs: int):
         lambda e: counter_uniform(key, c, e))(es))(cs)
 
 
+#: :func:`zipf_key` compiled whole, with its constants traced, as the
+#: engine runs it.  Op by op, the base ``eta * u - eta + 1`` rounds
+#: otherwise than XLA's fused form, and at 2**24 keys (exponent 100 at
+#: theta 0.99) one ulp of the base moves a rank by about 100: 3% of the
+#: ranks would differ from the device's.
+_zipf_key_traced = jax.jit(zipf_key)
+
+
 def key_table(seed, n_cores: int, n_epochs: int, n_keys: int,
               theta: float) -> np.ndarray:
     """Host reconstruction of the device key stream: ``[c, e]`` is the
@@ -149,7 +166,7 @@ def key_table(seed, n_cores: int, n_epochs: int, n_keys: int,
     it never perturbs existing entries)."""
     th, zeta, eta, alpha = zipf_consts(n_keys, theta)
     u = _u_grid(stream_key(seed, STREAM_KEY), n_cores, n_epochs)
-    return np.asarray(zipf_key(u, n_keys, th, zeta, eta, alpha))
+    return np.asarray(_zipf_key_traced(u, n_keys, th, zeta, eta, alpha))
 
 
 def lock_table(seed, n_cores: int, n_epochs: int, n_keys: int,

@@ -278,3 +278,84 @@ def test_many_threads_never_mix_or_lose_records():
     for r in recs:
         assert set(r["phases"]) == {"build", "dispatch", f"t{r['lanes']}"}
         assert r["compile_s"] == 0.0
+
+
+KEYED = sl.SimConfig(policy="ks_crew", sim_time_us=300.0, n_locks=4,
+                     n_keys=256, zipf_theta=0.99)
+
+
+def test_keys_span_nests_in_build_without_double_counting(monkeypatch):
+    """A keyed call times the Zipf constants in ``keys``, inside ``build``
+    and not again there; a key-off call opens no ``keys`` span."""
+    _sweep_job(KEYED)                             # no compile below
+    consts = sl.wlk.zipf_consts
+
+    def slow_consts(n_keys, theta):
+        time.sleep(0.1)
+        return consts(n_keys, theta)
+
+    monkeypatch.setattr(sl.wlk, "zipf_consts", slow_consts)
+    t0 = time.perf_counter()
+    _, recs = _new_records(lambda: sl.sweep(KEYED, AXES, slo_us=50.0))
+    wall = time.perf_counter() - t0
+    ph = recs[0]["phases"]
+    assert ph["keys"] >= 3 * 0.1                  # one per cell
+    assert ph["build"] < 0.1                      # its own time alone
+    assert ph["keys"] + ph["build"] + ph["dispatch"] <= wall
+    _, off = _new_records(_sweep_job)
+    assert all("keys" not in r["phases"] for r in off)
+    _, run_recs = _new_records(lambda: _run_job(KEYED))
+    assert run_recs[0]["phases"]["keys"] >= 0.1
+
+
+def test_key_draws_count_each_active_cores_epochs_and_the_next():
+    (_, sums), recs = _new_records(lambda: _sweep_job(KEYED))
+    summ = recs[1]
+    assert summ["kind"] == "sweep_summaries"
+    # each active core drew epoch 0's key, then one at each epoch's end
+    hand = sum(sum(s["epochs_per_core"]) + len(s["epochs_per_core"])
+               for s in sums)
+    assert [len(s["epochs_per_core"]) for s in sums] == AXES["n_cores"]
+    assert summ["key_draws"] == hand > sum(AXES["n_cores"])
+    assert "key_draws" not in recs[0]
+    (_, one), (_, srec) = _new_records(lambda: _run_job(KEYED))
+    assert srec["key_draws"] == sum(one["epochs_per_core"]) + KEYED.n_cores
+    for job in (_sweep_job, _run_job):
+        _, off = _new_records(job)
+        assert off[1]["key_draws"] == 0
+
+
+def test_compile_split_parts_are_each_at_most_compile_s(fresh_programs):
+    _, recs = _new_records(_sweep_job)
+    rec = recs[0]
+    parts = [rec[k] for k in ("trace_s", "lower_s", "backend_s")]
+    assert rec["compile_s"] > 0.0 and all(p > 0.0 for p in parts)
+    assert all(p <= rec["compile_s"] for p in parts)
+    assert rec["compile_s"] <= sum(parts) + 1e-9
+    assert rec["cache_load_s"] <= rec["backend_s"]
+    _, again = _new_records(_sweep_job)
+    assert all(again[0][k] == 0.0 for k in calllog.COMPILE_SPLIT.values())
+
+
+def test_compile_events_land_in_their_split_fields():
+    """Each JAX compile event's seconds go to its own field; a
+    persistent-cache load, nested in a backend compile, adds nothing to
+    ``compile_s``."""
+    with calllog.call("run") as rec:
+        with calllog.span("build"):
+            for event, secs in [
+                    ("/jax/core/compile/jaxpr_trace_duration", 0.25),
+                    ("/jax/core/compile/jaxpr_to_mlir_module_duration",
+                     0.125),
+                    ("/jax/compilation_cache/cache_retrieval_time_sec",
+                     0.5),
+                    ("/jax/core/compile/backend_compile_duration", 1.0),
+                    ("/jax/core/compile/something_else", 8.0)]:
+                jax.monitoring.record_event_duration_secs(event, secs)
+    assert rec["trace_s"] == pytest.approx(0.25, abs=1e-3)
+    assert rec["lower_s"] == pytest.approx(0.125, abs=1e-3)
+    assert rec["cache_load_s"] == pytest.approx(0.5, abs=1e-3)
+    assert rec["backend_s"] == pytest.approx(1.0, abs=1e-3)
+    # the events were recorded back to back, so the intervals overlap
+    assert rec["compile_s"] <= 1.375 + 1e-3
+    assert rec["compile_s"] >= 1.0 - 1e-3

@@ -80,6 +80,25 @@ def test_zipf_consts_validation():
     assert th != 1.0 and abs(th - 1.0) < 1e-3
 
 
+@pytest.mark.parametrize("n_keys", [2**24 + 1, 2**24 + 2, 2**25])
+def test_more_keys_than_float32_ranks_reach_are_refused(n_keys):
+    with pytest.raises(ValueError, match="float32"):
+        wlk.zipf_consts(n_keys, 0.99)
+    with pytest.raises(ValueError, match="float32"):
+        sl.SimConfig(n_locks=16, n_keys=n_keys)
+
+
+def test_2_24_keys_are_taken_and_reach_odd_tail_ranks():
+    cfg = sl.SimConfig(n_locks=16, n_keys=2**24, zipf_theta=0.99)
+    assert sl.build_params(cfg, 1e9).ks_keys == 2**24
+    tab = wlk.key_table(11, 4, 4096, 2**24, 0.99)
+    assert tab.min() >= 0 and tab.max() < 2**24
+    top = tab[tab > 2**23]
+    assert (top % 2 == 1).any() and (top % 2 == 0).any()
+    # every bucket of 16 is drawn, odd buckets among them
+    assert set(np.unique(tab % 16).tolist()) == set(range(16))
+
+
 def test_engine_lock_matches_host_reconstruction():
     """Closed loop: after a run, every core's current lock is the host
     ``lock_table`` entry at its completed-epoch index (epoch ``ep_cnt``
